@@ -43,8 +43,10 @@ virtual-device host, where fewer shards mean less contention; reported
 as measured).  Drill rows land in the same ``BENCH_scaling.json`` under
 ``|drill|`` keys.
 
-Runs in a SUBPROCESS so the virtual-device XLA_FLAGS never leak into the
-calling process (smoke tests and other benches must keep seeing 1 device).
+Virtual-device runs go to a CPU-only child process (``JAX_PLATFORMS=cpu``),
+so their XLA_FLAGS never leak into the calling process and they never
+contend for an accelerator the caller may hold; ``--no-force-host`` runs
+in-process on the real devices.
 
     PYTHONPATH=src:. python benchmarks/bench_scaling.py --smoke
     PYTHONPATH=src:. python benchmarks/bench_scaling.py --devices 1,2,4,8 \
@@ -63,83 +65,102 @@ import os
 import subprocess
 import sys
 
-_CHILD = r"""
-import json
-import os
-args = json.loads(%(args)r)
-if args["force_host"]:  # must happen before jax initialises
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=%(ndev)d "
-        + os.environ.get("XLA_FLAGS", ""))
-import jax
-from repro import configs
-from repro.data.synthetic import make_batch
-from repro.launch.mesh import make_data_mesh, make_grid_mesh
-from repro.models import get_model
-from repro.train.train_step import init_state, make_train_step
-from repro.tune.measure import median_time
-
-cfg = configs.get(args["arch"])
-model = get_model(cfg)
-params = model.init_params(jax.random.key(0), cfg)
-
-rows = []
-for dp, mp in args["layouts"]:
-    d = dp * mp
-    if mp > 1 and cfg.conv_channels %% mp:
-        raise SystemExit(
-            f"layout {dp}x{mp}: conv_channels={cfg.conv_channels} does not "
-            "divide over the model axis (pick a divisible arch, e.g. "
-            "atacworks-bf16 with C=K=16; DESIGN.md \N{SECTION SIGN}17)")
-    # the batch shards over the data axis only (devices along 'model'
-    # see the same shard), so --weak grows it with dp, not dp*mp
-    gbatch = args["batch"] * (dp if args["weak"] else 1)
-    mesh = make_data_mesh(dp) if mp == 1 else make_grid_mesh(dp, mp)
-    # d == 1 exercises the plain single-program step (the baseline);
-    # d > 1 the shard_map data/model-parallel path
-    step = jax.jit(make_train_step(
-        cfg, total_steps=100, mesh=mesh if d > 1 else None,
-        model_reduce_chunks=args["model_chunks"] if mp > 1 else None))
-    batch = make_batch(cfg, gbatch, args["width"], seed=0)
-    state = init_state(params)
-    sec = median_time(step, state, batch,
-                      iters=args["iters"], warmup=args["warmup"])
-    row = dict(devices=d, dp=dp, mp=mp, global_batch=gbatch,
-               local_batch=gbatch // dp, step_time_s=sec,
-               samples_per_s=gbatch / sec)
-    note = ""
-    if mp > 1:
-        # the chunked-vs-single model-psum head-to-head: same layout,
-        # bwd-data dx all-reduced in one piece instead of overlapped
-        # width chunks (DESIGN.md \N{SECTION SIGN}17)
-        single = jax.jit(make_train_step(cfg, total_steps=100, mesh=mesh))
-        sec1 = median_time(single, state, batch,
-                           iters=args["iters"], warmup=args["warmup"])
-        row["model_psum_single_s"] = sec1
-        row["model_psum_chunks"] = args["model_chunks"]
-        row["model_psum_chunked_speedup"] = sec1 / sec
-        note = f" psum-chunk x{sec1 / sec:.2f}"
-    rows.append(row)
-    print(f"# dp={dp:2d} mp={mp} batch={gbatch:3d} step={sec*1e3:8.1f}ms "
-          f"{gbatch/sec:8.2f} samples/s{note}", flush=True)
-print("JSON:" + json.dumps(rows))
-"""
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-_DRILL_CHILD = r"""
-import json
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=%(ndev)d "
-                           + os.environ.get("XLA_FLAGS", ""))
-args = json.loads(%(args)r)
-from repro.launch.train import run
-summary = run(["--arch", args["arch"], "--smoke",
-               "--steps", str(args["steps"]),
-               "--batch", str(args["batch"]), "--seq", str(args["seq"]),
-               "--ckpt-dir", args["ckpt_dir"], "--ckpt-every", "2",
-               "--faults", args["faults"]])
-print("JSON:" + json.dumps(summary))
-"""
+def _cpu_child_env(n_devices: int) -> dict:
+    """Environment of a virtual-device child: the CPU backend with
+    ``n_devices`` host devices.  The children are CPU drills by design, so
+    they never ask for (or wait on) an accelerator the parent may hold."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
+                        + env.get("XLA_FLAGS", ""))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(_ROOT, "src"), _ROOT, env.get("PYTHONPATH", "")])
+    return env
+
+
+def _run_child(src: str, arg: dict, n_devices: int) -> dict | list:
+    """Run ``src`` (which prints one ``JSON:`` line) in a CPU child."""
+    proc = subprocess.run([sys.executable, "-c", src, json.dumps(arg)],
+                          env=_cpu_child_env(n_devices), capture_output=True,
+                          text=True, timeout=3000)
+    sys.stderr.write(proc.stderr[-2000:] if proc.returncode else "")
+    for line in proc.stdout.splitlines():
+        if line.startswith("#"):
+            print(line)
+        if line.startswith("JSON:"):
+            return json.loads(line[5:])
+    raise RuntimeError(f"child failed:\n{proc.stdout}\n{proc.stderr}")
+
+
+_CHILD = ("import json, sys\n"
+          "from benchmarks.bench_scaling import measure\n"
+          "print('JSON:' + json.dumps(measure(json.loads(sys.argv[1]))))\n")
+
+_DRILL_CHILD = ("import json, sys\n"
+                "from repro.launch.train import run\n"
+                "print('JSON:' + json.dumps(run(json.loads(sys.argv[1]))))\n")
+
+
+def measure(args: dict) -> list[dict]:
+    """Time the train step on each (dp, mp) layout of ``args['layouts']``
+    over the devices of this process; returns one row per layout."""
+    import jax
+
+    from repro import configs
+    from repro.data.synthetic import make_batch
+    from repro.launch.mesh import make_data_mesh, make_grid_mesh
+    from repro.models import get_model
+    from repro.train.train_step import init_state, make_train_step
+    from repro.tune.measure import median_time
+
+    cfg = configs.get(args["arch"])
+    model = get_model(cfg)
+    params = model.init_params(jax.random.key(0), cfg)
+
+    rows = []
+    for dp, mp in args["layouts"]:
+        d = dp * mp
+        if mp > 1 and cfg.conv_channels % mp:
+            raise SystemExit(
+                f"layout {dp}x{mp}: conv_channels={cfg.conv_channels} does "
+                "not divide over the model axis (pick a divisible arch, e.g. "
+                "atacworks-bf16 with C=K=16; DESIGN.md §17)")
+        # the batch shards over the data axis only (devices along 'model'
+        # see the same shard), so --weak grows it with dp, not dp*mp
+        gbatch = args["batch"] * (dp if args["weak"] else 1)
+        mesh = make_data_mesh(dp) if mp == 1 else make_grid_mesh(dp, mp)
+        # d == 1 exercises the plain single-program step (the baseline);
+        # d > 1 the shard_map data/model-parallel path
+        step = jax.jit(make_train_step(
+            cfg, total_steps=100, mesh=mesh if d > 1 else None,
+            model_reduce_chunks=args["model_chunks"] if mp > 1 else None))
+        batch = make_batch(cfg, gbatch, args["width"], seed=0)
+        state = init_state(params)
+        sec = median_time(step, state, batch,
+                          iters=args["iters"], warmup=args["warmup"])
+        row = dict(devices=d, dp=dp, mp=mp, global_batch=gbatch,
+                   local_batch=gbatch // dp, step_time_s=sec,
+                   samples_per_s=gbatch / sec)
+        note = ""
+        if mp > 1:
+            # the chunked-vs-single model-psum head-to-head: same layout,
+            # bwd-data dx all-reduced in one piece instead of overlapped
+            # width chunks (DESIGN.md §17)
+            single = jax.jit(make_train_step(cfg, total_steps=100, mesh=mesh))
+            sec1 = median_time(single, state, batch,
+                               iters=args["iters"], warmup=args["warmup"])
+            row["model_psum_single_s"] = sec1
+            row["model_psum_chunks"] = args["model_chunks"]
+            row["model_psum_chunked_speedup"] = sec1 / sec
+            note = f" psum-chunk x{sec1 / sec:.2f}"
+        rows.append(row)
+        print(f"# dp={dp:2d} mp={mp} batch={gbatch:3d} "
+              f"step={sec * 1e3:8.1f}ms {gbatch / sec:8.2f} samples/s{note}",
+              flush=True)
+    return rows
 
 
 def run_drill(*, spec: str, arch: str = "atacworks", batch: int = 8,
@@ -149,21 +170,10 @@ def run_drill(*, spec: str, arch: str = "atacworks", batch: int = 8,
     import tempfile
 
     with tempfile.TemporaryDirectory() as ckdir:
-        child_args = dict(arch=arch, faults=spec, batch=batch, seq=seq,
-                          steps=steps, ckpt_dir=ckdir)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src"
-        src = _DRILL_CHILD % {"ndev": n_devices,
-                              "args": json.dumps(child_args)}
-        proc = subprocess.run([sys.executable, "-c", src], env=env,
-                              capture_output=True, text=True, timeout=3000)
-        for line in proc.stdout.splitlines():
-            if line.startswith("JSON:"):
-                summary = json.loads(line[5:])
-                break
-        else:
-            raise RuntimeError(
-                f"drill child failed:\n{proc.stdout}\n{proc.stderr}")
+        summary = _run_child(_DRILL_CHILD, [
+            "--arch", arch, "--smoke", "--steps", str(steps),
+            "--batch", str(batch), "--seq", str(seq), "--ckpt-dir", ckdir,
+            "--ckpt-every", "2", "--faults", spec], n_devices)
     rows = []
     for rec in summary["recoveries"]:
         rows.append(dict(
@@ -189,23 +199,12 @@ def run(*, arch: str, layouts: list[tuple[int, int]], batch: int, width: int,
         model_chunks: int = 2):
     child_args = dict(arch=arch, layouts=layouts, batch=batch, width=width,
                       iters=iters, warmup=warmup, weak=weak,
-                      force_host=force_host, model_chunks=model_chunks)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    src = _CHILD % {"ndev": max(dp * mp for dp, mp in layouts),
-                    "args": json.dumps(child_args)}
-    proc = subprocess.run([sys.executable, "-c", src], env=env,
-                          capture_output=True, text=True, timeout=3000)
-    sys.stderr.write(proc.stderr[-2000:] if proc.returncode else "")
-    for line in proc.stdout.splitlines():
-        if line.startswith("#"):
-            print(line)
-        if line.startswith("JSON:"):
-            rows = json.loads(line[5:])
-            break
-    else:
-        raise RuntimeError(
-            f"scaling child failed:\n{proc.stdout}\n{proc.stderr}")
+                      model_chunks=model_chunks)
+    if force_host:
+        rows = _run_child(_CHILD, child_args,
+                          max(dp * mp for dp, mp in layouts))
+    else:  # the real device set: this process owns it, no child
+        rows = measure(child_args)
     # baseline = the smallest device count actually run (1 in the default
     # and smoke lists); efficiency is relative to ITS per-device numbers
     base = min(rows, key=lambda r: r["devices"])

@@ -3,10 +3,10 @@
 TPU adaptation of Chaudhary et al. 2021 (see DESIGN.md §2).  The paper's
 LIBXSMM batch-reduce GEMM becomes an unrolled tap loop of MXU matmuls that
 accumulate into a single VMEM accumulator; the paper's cache blocking along
-the width dimension (block = 64 for AVX-512 L1/L2) becomes BlockSpec width
-tiling (block = WBLK, a multiple of the 128-lane TPU tile) with the *dilated
-footprint* ``F = WBLK + (S-1)*d`` staged HBM->VMEM once per tile via
-overlapping-window (element-indexed) BlockSpecs and reused by all S taps.
+the width dimension (block = 64 for AVX-512 L1/L2) becomes width tiling
+(block = WBLK, a multiple of the 128-lane TPU tile) with the *dilated
+footprint* ``F = WBLK + (S-1)*d`` staged HBM->VMEM once per tile and reused
+by all S taps.
 
 Three kernels behind one plan-driven entry (``conv1d_pass``), mirroring
 the paper's Algorithms 2-4:
@@ -19,6 +19,21 @@ the paper's Algorithms 2-4:
                               (C == K) variant used by Mamba2/Zamba2 causal
                               convs; runs on the VPU instead of the MXU.
 
+Every ``pallas_call`` carries a stable ``name`` (``conv1d_fwd``,
+``conv1d_bwd_data``, ``conv1d_bwd_weight``, ``dwconv1d_fwd``,
+``dwconv1d_bwd_data``, ``dwconv1d_bwd_weight``) so a profiler trace can
+find each pass.
+
+**Mosaic layout rules.**  The staged footprint is rounded up to whole
+128-lane tiles (``footprint``) and every window starts at a multiple of
+WBLK, so each staging copy is tile-aligned; the callers' width contract
+``Wp = Qp + (S-1)*d`` is kept and the tail up to the rounded footprint is
+zero-padded here.  Each tap is a *static* lane slice
+``x_ref[i, :, pl.ds(s*d, WBLK)]`` read straight from the VMEM ref.  The
+channel/filter dims must be multiples of the dtype's sublane tile
+(``sublane_tile``: 8 rows for 32-bit, 16 for 16-bit) when compiling for
+the chip: ``kernels/ops.py`` zero-pads them and slices the results back.
+
 The dense kernels support two **formulations** of the BRGEMM contraction
 (DESIGN.md §12), selected by ``alg``:
 
@@ -30,7 +45,7 @@ The dense kernels support two **formulations** of the BRGEMM contraction
                      footprint into one (S·C, WBLK) VMEM operand and
                      contracts it against the host-packed (KB, S·C) weight
                      tile in a **single** MXU matmul with contraction S·C
-                     (51·15 = 765 ≈ 6 full MXU passes instead of 51
+                     (51·16 = 816 ≈ 6 full MXU passes instead of 51
                      near-empty ones).  The price is the VMEM copy that
                      materialises the packed operand.
 
@@ -42,16 +57,23 @@ block staging over nblk samples.  ``repro.tune`` searches both axes per
 pass; the defaults (``tap_loop``, ``nblk=1``) reproduce the historical
 kernel exactly.
 
-Every kernel body also exists in a **software-pipelined** variant
-(``pipe >= 2``, DESIGN.md §15): the dilated footprint (and the cotangent
-tile, for bwd-weight) rotates through a ``pipe``-deep VMEM scratch via
-``pltpu.make_async_copy`` so the next tile's DMA is in flight while the
-current tile contracts, and the forward's fused-epilogue store streams
-out through a 2-slot buffer behind the next matmul.  In interpret mode
-the staging falls back to synchronous copies through the same buffers
-(``REPRO_PIPE_FORCE_ASYNC=1`` forces the real schedule for tests); the
-pipelined and synchronous bodies are bit-identical — same tap order,
-same fp32 accumulation.
+Staging has two schedules, selected by ``pipe`` (DESIGN.md §15):
+
+  * ``pipe = 0``  — the footprint is an element-indexed (overlapping
+                    window) BlockSpec; Pallas's own pipeline stages it.
+  * ``pipe >= 2`` — the footprint (and the cotangent tile, for
+                    bwd-weight) rotates through a ``pipe``-deep VMEM
+                    scratch via ``pltpu.make_async_copy`` so the next
+                    tile's DMA is in flight while the current tile
+                    contracts, and the forward's fused-epilogue store
+                    streams out through a 2-slot buffer behind the next
+                    matmul.  In interpret mode the staging falls back to
+                    synchronous copies through the same buffers
+                    (``REPRO_PIPE_FORCE_ASYNC=1`` forces the real schedule
+                    for tests).
+
+Both schedules run the same contraction code — same tap order, same fp32
+accumulation — so they are bit-identical.
 
 All kernels accept fp32 or bf16 inputs and accumulate in fp32
 (``preferred_element_type``), matching the AVX-512-BF16 contract.
@@ -85,15 +107,12 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .epilogue import ACTIVATIONS, canon
 
-try:  # TPU compiler params are optional (absent / ignored in interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 ALGS = ("tap_loop", "tap_packed")   # dense contraction formulations (§12)
+LANES = 128                          # TPU vreg lane count
 
 # Force the real async-DMA schedule even in interpret mode (the schedule-
 # equivalence tests use this; by default interpret runs the synchronous
@@ -107,6 +126,50 @@ def canon_pipe(pipe) -> int:
     kernel — a 1-deep "pipeline" has no lookahead), >= 2 -> that depth."""
     p = int(pipe or 0)
     return p if p >= 2 else 0
+
+
+def sublane_tile(dtype) -> int:
+    """Rows of one native (rows x 128) VMEM tile: 8 for 32-bit dtypes, 16
+    for 16-bit.  Channel/filter dims that sit on the sublane axis of a
+    kernel block must be multiples of it when compiling for the chip."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def footprint(wblk: int, S: int, dilation: int) -> int:
+    """Staged footprint width: the dilated window ``WBLK + (S-1)*d``
+    rounded up to whole 128-lane tiles (the tail is zeros no tap reads)."""
+    F = wblk + (S - 1) * dilation
+    return -(-F // LANES) * LANES
+
+
+def _stage_width(x, Qp: int, wblk: int, Fp: int):
+    """Zero-pad x's width so the last tile's rounded footprint is in
+    bounds: Wx = (Qp/WBLK - 1)*WBLK + Fp >= Qp + (S-1)*d."""
+    need = Qp - wblk + Fp
+    W = x.shape[-1]
+    return x if W >= need else jnp.pad(x, ((0, 0), (0, 0), (0, need - W)))
+
+
+def _check_tiling(interpret: bool, wblk: int, **rows):
+    """Refuse, with the reason, a compiled call whose blocks break the
+    chip's tiling; interpret mode accepts any shape."""
+    if interpret:
+        return
+    if wblk % LANES:
+        raise ValueError(f"wblk={wblk} must be a multiple of {LANES} lanes")
+    for what, (n, dtype) in rows.items():
+        if n % sublane_tile(dtype):
+            raise ValueError(
+                f"{what}={n} is not a multiple of the {jnp.dtype(dtype).name}"
+                f" sublane tile ({sublane_tile(dtype)}); kernels/ops.py pads "
+                "channel and filter dims before calling the kernels")
+
+
+def _lane_offset(t, wblk: int):
+    """Element offset of width tile ``t``, marked as a multiple of WBLK so
+    Mosaic can prove the staging copy is lane-aligned."""
+    off = t * wblk
+    return off if isinstance(off, int) else pl.multiple_of(off, wblk)
 
 
 def _sync_staging(interpret: bool) -> bool:
@@ -189,15 +252,17 @@ def _store_start(qt, q_tiles: int, make_copy, sync: bool):
         make_copy(qt).wait()
 
 
-def default_cblk(C: int, cap: int = 512) -> int:
+def default_cblk(C: int, cap: int = 512, align: int = 1) -> int:
     """Depthwise channel-tile default: the largest divisor of C that is
-    <= cap.  (``min(C, cap)`` is wrong for any C > cap not divisible by
-    cap — e.g. C=768 tripped the ``C % cblk == 0`` contract.)  Shared with
-    ``tune.space``'s legality/VMEM accounting so the tuner and the untuned
-    default agree on the tile actually run."""
+    <= cap and a multiple of ``align`` (C itself when C <= cap or no such
+    divisor exists).  (``min(C, cap)`` is wrong for any C > cap not
+    divisible by cap — e.g. C=768 tripped the ``C % cblk == 0`` contract.)
+    Shared with ``tune.space``'s legality/VMEM accounting so the tuner and
+    the untuned default agree on the tile actually run."""
     if C <= cap:
         return C
-    return max(d for d in range(1, cap + 1) if C % d == 0)
+    divs = [d for d in range(align, cap + 1, align) if C % d == 0]
+    return max(divs) if divs else C
 
 
 def conv1d_pass(pass_: str, *args, depthwise: bool = False, **kw):
@@ -212,48 +277,32 @@ def conv1d_pass(pass_: str, *args, depthwise: bool = False, **kw):
     plain kwargs, so the tuner, the ops-layer VJP, and a direct caller all
     drive the same dispatch.
     """
+    prefix = "dwconv1d" if depthwise else "conv1d"
     if pass_ == "bwd_weight":
         fn = depthwise_conv1d_bwd_weight if depthwise else conv1d_bwd_weight
     elif pass_ in ("fwd", "bwd_data"):
         fn = depthwise_conv1d_fwd if depthwise else conv1d_fwd
     else:
         raise ValueError(f"unknown conv pass {pass_!r}")
-    return fn(*args, **kw)
+    return fn(*args, name=f"{prefix}_{pass_}", **kw)
 
 
-def _compiler_params(dimension_semantics: Sequence[str], interpret: bool):
-    if interpret or pltpu is None:
-        return None
-    try:
-        return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics))
-    except TypeError:  # pragma: no cover - older API spelling
-        return None
+def _compiler_params(dimension_semantics: Sequence[str]):
+    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics))
 
 
-def _overlap_spec(block_shape, index_map):
-    """Overlapping-window BlockSpec along the last (width) axis.
+def _footprint_spec(block_rows: tuple[int, int], index_map):
+    """Overlapping-window BlockSpec of the staged footprint.
 
-    The dilated footprint ``F = WBLK + (S-1)*d`` of adjacent width tiles
-    overlaps by ``(S-1)*d`` elements, so the window axis must be indexed in
-    *elements*, not blocks.  ``index_map`` follows the newer-jax
-    ``pl.Element`` convention: BLOCK indices for the leading (Blocked) axes,
-    an ELEMENT offset for the window axis.  jax <= 0.5 only has the
-    all-element ``Unblocked`` indexing mode, so there the leading block
-    indices are scaled by their block sizes here.
-    """
-    if hasattr(pl, "Element"):
-        shape = (*block_shape[:-1], pl.Element(block_shape[-1]))
-        return pl.BlockSpec(shape, index_map)
-
-    def elem_map(*grid_ids):
-        idx = index_map(*grid_ids)
-        return (*(i * b for i, b in zip(idx[:-1], block_shape[:-1])), idx[-1])
-
-    return pl.BlockSpec(block_shape, elem_map, indexing_mode=pl.Unblocked())
+    Adjacent width tiles' footprints overlap by ``Fp - WBLK`` elements, so
+    the window axis is indexed in *elements*; Pallas requires every block
+    dim to be element-indexed then, so ``index_map`` returns element
+    offsets for all three axes.  ``block_rows`` is (leading, rows, Fp)."""
+    return pl.BlockSpec(tuple(pl.Element(b) for b in block_rows), index_map)
 
 
 # ---------------------------------------------------------------------------
-# Forward (Algorithm 2) — also the bwd-data engine (Algorithm 3)
+# Contraction bodies, shared by both staging schedules
 # ---------------------------------------------------------------------------
 
 
@@ -273,10 +322,9 @@ def _epilogue_on_acc(acc, b_ref, r, activation: str):
 
 def _folded_tap(x_ref, s: int, dilation: int, wblk: int, nblk: int):
     """Width-slice of the staged footprint for tap ``s``, batch-folded:
-    (C, nblk·WBLK) — each sample's (C, WBLK) slice concatenated along the
-    GEMM width dimension."""
-    cols = [jax.lax.dynamic_slice_in_dim(x_ref[i], s * dilation, wblk, axis=1)
-            for i in range(nblk)]
+    (C, nblk·WBLK) — each sample's (C, WBLK) slice, read from the VMEM ref
+    at a static lane offset, concatenated along the GEMM width dim."""
+    cols = [x_ref[i, :, pl.ds(s * dilation, wblk)] for i in range(nblk)]
     return cols[0] if nblk == 1 else jnp.concatenate(cols, axis=1)
 
 
@@ -306,31 +354,67 @@ def _gather_taps(x_ref, S: int, dilation: int, wblk: int, nblk: int):
     return parts[0] if nblk == 1 else jnp.concatenate(parts, axis=2)
 
 
-def _packed_fwd_acc(w_ref, x_ref, S: int, dilation: int, wblk: int,
-                    nblk: int, gather: bool):
-    """acc (KB, nblk·WBLK) — the single packed GEMM with contraction S·C.
-    w_ref is the host-packed (KB, S·C) tile."""
-    if gather:
-        xg = _gather_taps(x_ref, S, dilation, wblk, nblk)   # (C, S, nW)
-        wv = w_ref[...].reshape(w_ref.shape[0], S, -1)      # (KB, S, C)
-        return jax.lax.dot_general(wv, xg, (((1, 2), (1, 0)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    xp = _pack_taps(x_ref, S, dilation, wblk, nblk)         # (S*C, nW)
-    return jnp.dot(w_ref[...], xp, preferred_element_type=jnp.float32)
+def _mxu_operands(a, b):
+    """Cast both operands to their common dtype (Mosaic's matmul takes one
+    dtype; a bf16 weight against the fp32 stem input widens exactly) and
+    pick the contraction precision: fp32 contracts at full fp32 on the MXU
+    (Mosaic's default may take bf16 passes), bf16 needs no flag."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    prec = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
+    return a.astype(dt), b.astype(dt), prec
 
 
-def _packed_bwd_w(g, x_ref, S: int, dilation: int, wblk: int, nblk: int,
-                  gather: bool):
-    """One (K, nblk·WBLK)×(nblk·WBLK, S·C) GEMM per grid step: the packed
-    weight-gradient update, tap-major (K, S·C) to match the resident
-    output block."""
-    if gather:
-        xg = _gather_taps(x_ref, S, dilation, wblk, nblk)   # (C, S, nW)
-        dwp = jax.lax.dot_general(g, xg, (((1,), (2,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dwp.transpose(0, 2, 1).reshape(g.shape[0], -1)  # (K, S*C)
-    xp = _pack_taps(x_ref, S, dilation, wblk, nblk)
-    return jnp.dot(g, xp.T, preferred_element_type=jnp.float32)
+def _dot(a, b):
+    """a (M, C) · b (C, W) -> (M, W), fp32 accumulation."""
+    a, b, prec = _mxu_operands(a, b)
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
+
+
+def _dot_general(a, b, dims):
+    a, b, prec = _mxu_operands(a, b)
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a (M, W) · b (R, W)ᵀ -> (M, R) fp32, contracting the width dims —
+    the MXU's transposed-rhs form, no transpose materialised."""
+    return _dot_general(a, b, ((1,), (1,)))
+
+
+def _fwd_acc(x_ref, w_ref, *, S: int, dilation: int, wblk: int, nblk: int,
+             alg: str, gather: bool):
+    """acc (KB, nblk·WBLK) fp32 for one tile.  tap_loop: S small GEMMs
+    (the BRGEMM batch-reduce); tap_packed: one GEMM with contraction S·C
+    against the host-packed (KB, S·C) tile."""
+    if alg == "tap_packed":
+        if gather:
+            xg = _gather_taps(x_ref, S, dilation, wblk, nblk)   # (C, S, nW)
+            wv = w_ref[...].reshape(w_ref.shape[0], S, -1)      # (KB, S, C)
+            return _dot_general(wv, xg, ((1, 2), (1, 0)))
+        return _dot(w_ref[...], _pack_taps(x_ref, S, dilation, wblk, nblk))
+    acc = jnp.zeros((w_ref.shape[1], nblk * wblk), jnp.float32)
+    for s in range(S):  # the BRGEMM batch-reduce dimension (unrolled taps)
+        acc += _dot(w_ref[s], _folded_tap(x_ref, s, dilation, wblk, nblk))
+    return acc
+
+
+def _bwd_w_accumulate(o_ref, g, x_ref, *, S: int, dilation: int, wblk: int,
+                      nblk: int, alg: str, gather: bool):
+    """o_ref += this tile's weight-gradient contribution.  tap_loop: S
+    (K, nW)×(nW, C) GEMMs into the (S, K, C) block; tap_packed: one
+    (K, nW)×(nW, S·C) GEMM into the tap-major (K, S·C) block."""
+    if alg == "tap_packed":
+        if gather:
+            xg = _gather_taps(x_ref, S, dilation, wblk, nblk)   # (C, S, nW)
+            dwp = _dot_general(g, xg, ((1,), (2,)))
+            o_ref[...] += dwp.transpose(0, 2, 1).reshape(g.shape[0], -1)
+        else:
+            o_ref[...] += _dot_nt(g, _pack_taps(x_ref, S, dilation, wblk,
+                                                nblk))
+        return
+    for s in range(S):  # S small GEMMs per width block (Alg. 4 line 4)
+        o_ref[s] += _dot_nt(g, _folded_tap(x_ref, s, dilation, wblk, nblk))
 
 
 def _fold(ref, nblk: int):
@@ -340,12 +424,17 @@ def _fold(ref, nblk: int):
             jnp.concatenate([ref[i] for i in range(nblk)], axis=1))
 
 
+# ---------------------------------------------------------------------------
+# Forward (Algorithm 2) — also the bwd-data engine (Algorithm 3)
+# ---------------------------------------------------------------------------
+
+
 def _fwd_kernel(*refs, S: int, dilation: int, wblk: int, nblk: int, alg: str,
                 gather: bool, activation: str, has_bias: bool,
                 has_residual: bool, save_preact: bool):
     """One (n-fold, k-tile, q-tile) grid cell.
 
-    x_ref : (nblk, C, F)     dilated footprints of nblk samples (VMEM)
+    x_ref : (nblk, C, Fp)    dilated footprints of nblk samples (VMEM)
     w_ref : (S, KB, C)       all taps of this filter tile  [tap_loop]
             (KB, S*C)        host-packed filter tile       [tap_packed]
     b_ref : (KB, 1)          bias tile            (iff has_bias)
@@ -360,16 +449,8 @@ def _fwd_kernel(*refs, S: int, dilation: int, wblk: int, nblk: int, alg: str,
     o_ref = next(it)
     u_ref = next(it) if save_preact else None
 
-    if alg == "tap_packed":
-        # the whole tap loop collapses into a single MXU matmul with
-        # contraction S*C against the host-packed (KB, S*C) tile
-        acc = _packed_fwd_acc(w_ref, x_ref, S, dilation, wblk, nblk, gather)
-    else:
-        acc = jnp.zeros((w_ref.shape[1], nblk * wblk), jnp.float32)
-        for s in range(S):  # the BRGEMM batch-reduce dimension (unrolled taps)
-            a = w_ref[s]  # (KB, C)
-            b = _folded_tap(x_ref, s, dilation, wblk, nblk)  # (C, nblk*WBLK)
-            acc += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc = _fwd_acc(x_ref, w_ref, S=S, dilation=dilation, wblk=wblk,
+                   nblk=nblk, alg=alg, gather=gather)
     r = _fold(r_ref, nblk) if has_residual else None
     u, y = _epilogue_on_acc(acc, b_ref, r, activation)
     for i in range(nblk):  # unfold the GEMM width back into per-sample tiles
@@ -382,7 +463,7 @@ def _fwd_kernel(*refs, S: int, dilation: int, wblk: int, nblk: int, alg: str,
 def _fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
                      kblk: int, alg: str, gather: bool, activation: str,
                      has_bias: bool, has_residual: bool, save_preact: bool,
-                     pipe: int, q_tiles: int, sync: bool):
+                     pipe: int, q_tiles: int, Fp: int, sync: bool):
     """Software-pipelined ``_fwd_kernel`` (DESIGN.md §15).
 
     x and the activated output live in ANY (HBM on TPU); the dilated
@@ -403,24 +484,16 @@ def _fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
     xbuf, xsem, obuf, osem = next(it), next(it), next(it), next(it)
 
     n, kt, qt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    F = wblk + (S - 1) * dilation
 
     def x_copy(t):
         return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(n * nblk, nblk), :, pl.ds(t * wblk, F)],
+            x_hbm.at[pl.ds(n * nblk, nblk), :,
+                     pl.ds(_lane_offset(t, wblk), Fp)],
             xbuf.at[t % pipe], xsem.at[t % pipe])
 
     _pipe_schedule(qt, q_tiles, pipe, x_copy, sync)
-    xs = xbuf[qt % pipe]                       # (nblk, C, F), staged
-
-    if alg == "tap_packed":
-        acc = _packed_fwd_acc(w_ref, xs, S, dilation, wblk, nblk, gather)
-    else:
-        acc = jnp.zeros((w_ref.shape[1], nblk * wblk), jnp.float32)
-        for s in range(S):
-            a = w_ref[s]
-            b = _folded_tap(xs, s, dilation, wblk, nblk)
-            acc += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc = _fwd_acc(xbuf.at[qt % pipe], w_ref, S=S, dilation=dilation,
+                   wblk=wblk, nblk=nblk, alg=alg, gather=gather)
     r = _fold(r_ref, nblk) if has_residual else None
     u, y = _epilogue_on_acc(acc, b_ref, r, activation)
 
@@ -428,7 +501,7 @@ def _fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
         return pltpu.make_async_copy(
             obuf.at[t % 2],
             o_hbm.at[pl.ds(n * nblk, nblk), pl.ds(kt * kblk, kblk),
-                     pl.ds(t * wblk, wblk)],
+                     pl.ds(_lane_offset(t, wblk), wblk)],
             osem.at[t % 2])
 
     _store_wait_slot(qt, o_copy, sync)
@@ -438,55 +511,6 @@ def _fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
             u_ref[i] = u[:, blk]
         obuf[qt % 2, i] = y[:, blk].astype(obuf.dtype)
     _store_start(qt, q_tiles, o_copy, sync)
-
-
-def _conv1d_fwd_pipe(x, w_in, bias, residual, *, N, C, K, S, Qp, dilation,
-                     wblk, kblk, alg, nblk, pipe, out_dtype, activation,
-                     save_preact, interpret):
-    """pallas_call plumbing of the pipelined forward: ANY-space x/y refs,
-    rotating footprint scratch + 2-slot store buffer + DMA semaphores."""
-    F = wblk + (S - 1) * dilation
-    grid = (N // nblk, K // kblk, Qp // wblk)
-    if alg == "tap_packed":
-        w_spec = pl.BlockSpec((kblk, S * C), lambda n, kt, qt: (kt, 0))
-    else:
-        w_spec = pl.BlockSpec((S, kblk, C), lambda n, kt, qt: (0, kt, 0))
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY), w_spec]
-    inputs = [x, w_in]
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((kblk, 1), lambda n, kt, qt: (kt, 0)))
-        inputs.append(bias.reshape(K, 1))
-    if residual is not None:
-        in_specs.append(pl.BlockSpec((nblk, kblk, wblk),
-                                     lambda n, kt, qt: (n, kt, qt)))
-        inputs.append(residual)
-    out_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
-    out_shape = [jax.ShapeDtypeStruct((N, K, Qp), out_dtype)]
-    if save_preact:
-        out_specs.append(pl.BlockSpec((nblk, kblk, wblk),
-                                      lambda n, kt, qt: (n, kt, qt)))
-        out_shape.append(jax.ShapeDtypeStruct((N, K, Qp), jnp.float32))
-    scratch = [pltpu.VMEM((pipe, nblk, C, F), x.dtype),
-               pltpu.SemaphoreType.DMA((pipe,)),
-               pltpu.VMEM((2, nblk, kblk, wblk), out_dtype),
-               pltpu.SemaphoreType.DMA((2,))]
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel_pipe, S=S, dilation=dilation, wblk=wblk,
-                          nblk=nblk, kblk=kblk, alg=alg, gather=interpret,
-                          activation=activation, has_bias=bias is not None,
-                          has_residual=residual is not None,
-                          save_preact=save_preact, pipe=pipe,
-                          q_tiles=Qp // wblk,
-                          sync=_sync_staging(interpret)),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs if save_preact else out_specs[0],
-        out_shape=out_shape if save_preact else out_shape[0],
-        scratch_shapes=scratch,
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary"), interpret),
-        interpret=interpret,
-    )(*inputs)
 
 
 def conv1d_fwd(
@@ -505,6 +529,7 @@ def conv1d_fwd(
     pipe: int = 0,
     out_dtype=None,
     interpret: bool = False,
+    name: str = "conv1d_fwd",
 ):
     """BRGEMM forward pass.  x: (N, C, Qp + (S-1)*d), w: (S, K, C) -> (N, K, Qp).
 
@@ -522,31 +547,25 @@ def conv1d_fwd(
     fused-epilogue store streams behind the next tile's matmul.  Bit-
     identical to the synchronous kernel (same tap order, same fp32
     accumulation); in interpret mode the staging falls back to synchronous
-    copies through the same buffers.
+    copies through the same buffers.  ``name`` labels the kernel in
+    profiler traces (``conv1d_bwd_data`` when it runs Alg. 3).
     """
     N, C, Wp = x.shape
     S, K, Cw = w.shape
     assert C == Cw, (C, Cw)
     assert alg in ALGS, alg
     assert N % nblk == 0, (N, nblk)
-    F = wblk + (S - 1) * dilation
     Qp = Wp - (S - 1) * dilation
     assert Qp % wblk == 0, (Qp, wblk)
     kblk = kblk or K
     assert K % kblk == 0, (K, kblk)
-    grid = (N // nblk, K // kblk, Qp // wblk)
     out_dtype = out_dtype or x.dtype
+    _check_tiling(interpret, wblk, C=(C, x.dtype), kblk=(kblk, out_dtype))
+    Fp = footprint(wblk, S, dilation)
+    x = _stage_width(x, Qp, wblk, Fp)
+    grid = (N // nblk, K // kblk, Qp // wblk)
     activation = canon(activation)
-    pipe = canon_pipe(pipe) if pltpu is not None else 0
-
-    if pipe:
-        w_in = (w.transpose(1, 0, 2).reshape(K, S * C)
-                if alg == "tap_packed" else w)
-        return _conv1d_fwd_pipe(
-            x, w_in, bias, residual, N=N, C=C, K=K, S=S, Qp=Qp,
-            dilation=dilation, wblk=wblk, kblk=kblk, alg=alg, nblk=nblk,
-            pipe=pipe, out_dtype=out_dtype, activation=activation,
-            save_preact=save_preact, interpret=interpret)
+    pipe = canon_pipe(pipe)
 
     if alg == "tap_packed":
         # host-side pre-pack: (S, K, C) -> (K, S*C), so the kernel's single
@@ -557,11 +576,16 @@ def conv1d_fwd(
     else:
         w_in = w
         w_spec = pl.BlockSpec((S, kblk, C), lambda n, kt, qt: (0, kt, 0))
-    in_specs = [
-        # overlapping dilated footprint along width: element-indexed
-        _overlap_spec((nblk, C, F), lambda n, kt, qt: (n, 0, qt * wblk)),
-        w_spec,
-    ]
+    tile = pl.BlockSpec((nblk, kblk, wblk), lambda n, kt, qt: (n, kt, qt))
+    if pipe:
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
+        y_spec = pl.BlockSpec(memory_space=pl.ANY)
+    else:
+        x_spec = _footprint_spec(
+            (nblk, C, Fp),
+            lambda n, kt, qt: (n * nblk, 0, _lane_offset(qt, wblk)))
+        y_spec = tile
+    in_specs = [x_spec, w_spec]
     inputs = [x, w_in]
     if bias is not None:
         assert bias.shape == (K,), (bias.shape, K)
@@ -569,31 +593,42 @@ def conv1d_fwd(
         inputs.append(bias.reshape(K, 1))
     if residual is not None:
         assert residual.shape == (N, K, Qp), (residual.shape, (N, K, Qp))
-        in_specs.append(pl.BlockSpec((nblk, kblk, wblk),
-                                     lambda n, kt, qt: (n, kt, qt)))
+        in_specs.append(tile)
         inputs.append(residual)
-
-    out_spec = pl.BlockSpec((nblk, kblk, wblk), lambda n, kt, qt: (n, kt, qt))
-    out_specs = [out_spec]
+    out_specs = [y_spec]
     out_shape = [jax.ShapeDtypeStruct((N, K, Qp), out_dtype)]
     if save_preact:
-        out_specs.append(out_spec)
+        out_specs.append(tile)
         out_shape.append(jax.ShapeDtypeStruct((N, K, Qp), jnp.float32))
 
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, S=S, dilation=dilation, wblk=wblk,
-                          nblk=nblk, alg=alg, gather=interpret,
-                          activation=activation, has_bias=bias is not None,
-                          has_residual=residual is not None,
-                          save_preact=save_preact),
+    common = dict(S=S, dilation=dilation, wblk=wblk, nblk=nblk, alg=alg,
+                  gather=interpret, activation=activation,
+                  has_bias=bias is not None,
+                  has_residual=residual is not None, save_preact=save_preact)
+    if pipe:
+        kernel = functools.partial(_fwd_kernel_pipe, kblk=kblk, pipe=pipe,
+                                   q_tiles=Qp // wblk, Fp=Fp,
+                                   sync=_sync_staging(interpret), **common)
+        scratch = [pltpu.VMEM((pipe, nblk, C, Fp), x.dtype),
+                   pltpu.SemaphoreType.DMA((pipe,)),
+                   pltpu.VMEM((2, nblk, kblk, wblk), out_dtype),
+                   pltpu.SemaphoreType.DMA((2,))]
+        dims = ("parallel", "parallel", "arbitrary")
+    else:
+        kernel = functools.partial(_fwd_kernel, **common)
+        scratch = []
+        dims = ("parallel", "parallel", "parallel")
+    return pl.pallas_call(
+        kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_specs if save_preact else out_spec,
+        out_specs=out_specs if save_preact else out_specs[0],
         out_shape=out_shape if save_preact else out_shape[0],
-        compiler_params=_compiler_params(("parallel", "parallel", "parallel"), interpret),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(dims),
         interpret=interpret,
+        name=name,
     )(*inputs)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -608,7 +643,7 @@ def _bwd_w_kernel(x_ref, g_ref, o_ref, *dbias_ref, S: int, dilation: int,
     output block is revisited every step and accumulated into — the paper's
     shared weight-gradient buffer across width blocks and batch threads.
 
-    x_ref : (nblk, C, F), g_ref : (nblk, K, WBLK),
+    x_ref : (nblk, C, Fp), g_ref : (nblk, K, WBLK),
     o_ref : (S, K, C) fp32 [tap_loop] or (K, S*C) fp32 [tap_packed — one
     (K, nblk·WBLK)×(nblk·WBLK, S·C) GEMM per grid step; the wrapper
     unpacks], dbias_ref : (K, 1) fp32 (iff with_dbias) — the fused
@@ -624,13 +659,8 @@ def _bwd_w_kernel(x_ref, g_ref, o_ref, *dbias_ref, S: int, dilation: int,
             dbias_ref[0][...] = jnp.zeros_like(dbias_ref[0])
 
     g = _fold(g_ref, nblk)  # (K, nblk*WBLK)
-    if alg == "tap_packed":
-        o_ref[...] += _packed_bwd_w(g, x_ref, S, dilation, wblk, nblk,
-                                    gather)
-    else:
-        for s in range(S):  # S small GEMMs per width block (Alg. 4 line 4)
-            b = _folded_tap(x_ref, s, dilation, wblk, nblk)  # (C, nblk*WBLK)
-            o_ref[s] += jnp.dot(g, b.T, preferred_element_type=jnp.float32)
+    _bwd_w_accumulate(o_ref, g, x_ref, S=S, dilation=dilation, wblk=wblk,
+                      nblk=nblk, alg=alg, gather=gather)
     if with_dbias:
         dbias_ref[0][...] += jnp.sum(g.astype(jnp.float32), axis=-1,
                                      keepdims=True)
@@ -638,7 +668,7 @@ def _bwd_w_kernel(x_ref, g_ref, o_ref, *dbias_ref, S: int, dilation: int,
 
 def _bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
                        alg: str, gather: bool, with_dbias: bool, pipe: int,
-                       nq: int, total: int, sync: bool):
+                       nq: int, total: int, Fp: int, sync: bool):
     """Software-pipelined ``_bwd_w_kernel``: both operand tiles (footprint
     + cotangent) rotate through ``pipe``-deep VMEM scratch, indexed by the
     flattened sequential step ``n·nq + qt`` — the whole grid is one
@@ -650,7 +680,6 @@ def _bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
     dbias_ref = next(it) if with_dbias else None
     xbuf, xsem, gbuf, gsem = next(it), next(it), next(it), next(it)
 
-    F = wblk + (S - 1) * dilation
     step = pl.program_id(0) * nq + pl.program_id(1)
 
     def copies(t):
@@ -658,10 +687,12 @@ def _bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
         a, b = t // nq, t % nq
         return _MultiCopy([
             pltpu.make_async_copy(
-                x_hbm.at[pl.ds(a * nblk, nblk), :, pl.ds(b * wblk, F)],
+                x_hbm.at[pl.ds(a * nblk, nblk), :,
+                         pl.ds(_lane_offset(b, wblk), Fp)],
                 xbuf.at[slot], xsem.at[slot]),
             pltpu.make_async_copy(
-                g_hbm.at[pl.ds(a * nblk, nblk), :, pl.ds(b * wblk, wblk)],
+                g_hbm.at[pl.ds(a * nblk, nblk), :,
+                         pl.ds(_lane_offset(b, wblk), wblk)],
                 gbuf.at[slot], gsem.at[slot])])
 
     _pipe_schedule(step, total, pipe, copies, sync)
@@ -672,14 +703,9 @@ def _bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, nblk: int,
         if with_dbias:
             dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
-    xs = xbuf[step % pipe]                     # (nblk, C, F), staged
-    g = _fold(gbuf[step % pipe], nblk)         # (K, nblk*WBLK)
-    if alg == "tap_packed":
-        o_ref[...] += _packed_bwd_w(g, xs, S, dilation, wblk, nblk, gather)
-    else:
-        for s in range(S):
-            b = _folded_tap(xs, s, dilation, wblk, nblk)
-            o_ref[s] += jnp.dot(g, b.T, preferred_element_type=jnp.float32)
+    g = _fold(gbuf.at[step % pipe], nblk)      # (K, nblk*WBLK)
+    _bwd_w_accumulate(o_ref, g, xbuf.at[step % pipe], S=S, dilation=dilation,
+                      wblk=wblk, nblk=nblk, alg=alg, gather=gather)
     if with_dbias:
         dbias_ref[...] += jnp.sum(g.astype(jnp.float32), axis=-1,
                                   keepdims=True)
@@ -697,6 +723,7 @@ def conv1d_bwd_weight(
     pipe: int = 0,
     with_dbias: bool = False,
     interpret: bool = False,
+    name: str = "conv1d_bwd_weight",
 ):
     """BRGEMM weight gradient.  x: (N, C, Qp+(S-1)d), gout: (N, K, Qp) -> (S, K, C) fp32.
 
@@ -711,10 +738,12 @@ def conv1d_bwd_weight(
     assert N == Ng and Qp % wblk == 0 and Wp == Qp + (S - 1) * dilation
     assert alg in ALGS, alg
     assert N % nblk == 0, (N, nblk)
-    F = wblk + (S - 1) * dilation
+    _check_tiling(interpret, wblk, C=(C, x.dtype), K=(K, gout.dtype))
+    Fp = footprint(wblk, S, dilation)
+    x = _stage_width(x, Qp, wblk, Fp)
     grid = (N // nblk, Qp // wblk)
     packed = alg == "tap_packed"
-    pipe = canon_pipe(pipe) if pltpu is not None else 0
+    pipe = canon_pipe(pipe)
 
     if packed:
         out_specs = pl.BlockSpec((K, S * C), lambda n, qt: (0, 0))
@@ -726,24 +755,24 @@ def conv1d_bwd_weight(
         out_specs = [out_specs, pl.BlockSpec((K, 1), lambda n, qt: (0, 0))]
         out_shape = [out_shape, jax.ShapeDtypeStruct((K, 1), jnp.float32)]
 
+    common = dict(S=S, dilation=dilation, wblk=wblk, nblk=nblk, alg=alg,
+                  gather=interpret, with_dbias=with_dbias)
     if pipe:
         nq = Qp // wblk
         kernel = functools.partial(
-            _bwd_w_kernel_pipe, S=S, dilation=dilation, wblk=wblk, nblk=nblk,
-            alg=alg, gather=interpret, with_dbias=with_dbias, pipe=pipe,
-            nq=nq, total=(N // nblk) * nq, sync=_sync_staging(interpret))
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY)]
-        scratch = [pltpu.VMEM((pipe, nblk, C, F), x.dtype),
+            _bwd_w_kernel_pipe, pipe=pipe, nq=nq, total=(N // nblk) * nq,
+            Fp=Fp, sync=_sync_staging(interpret), **common)
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)]
+        scratch = [pltpu.VMEM((pipe, nblk, C, Fp), x.dtype),
                    pltpu.SemaphoreType.DMA((pipe,)),
                    pltpu.VMEM((pipe, nblk, K, wblk), gout.dtype),
                    pltpu.SemaphoreType.DMA((pipe,))]
     else:
-        kernel = functools.partial(
-            _bwd_w_kernel, S=S, dilation=dilation, wblk=wblk, nblk=nblk,
-            alg=alg, gather=interpret, with_dbias=with_dbias)
+        kernel = functools.partial(_bwd_w_kernel, **common)
         in_specs = [
-            _overlap_spec((nblk, C, F), lambda n, qt: (n, 0, qt * wblk)),
+            _footprint_spec((nblk, C, Fp), lambda n, qt: (
+                n * nblk, 0, _lane_offset(qt, wblk))),
             pl.BlockSpec((nblk, K, wblk), lambda n, qt: (n, 0, qt)),
         ]
         scratch = []
@@ -755,8 +784,9 @@ def conv1d_bwd_weight(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(("arbitrary", "arbitrary"), interpret),
+        compiler_params=_compiler_params(("arbitrary", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(x, gout)
     dw, db = out if with_dbias else (out, None)
     if packed:  # unpack (K, S*C) tap-major rows back to the (S, K, C) layout
@@ -771,10 +801,20 @@ def conv1d_bwd_weight(
 # ---------------------------------------------------------------------------
 
 
+def _dw_acc(x_ref, w_ref, *, S: int, dilation: int, wblk: int):
+    """(CB, WBLK) fp32 VPU fma chain.  x_ref: (1, CB, Fp) staged footprint;
+    w_ref: (CB, S) channel-major taps, read one (CB, 1) column per tap."""
+    acc = jnp.zeros((x_ref.shape[1], wblk), jnp.float32)
+    for s in range(S):
+        acc += (w_ref[:, pl.ds(s, 1)].astype(jnp.float32)
+                * x_ref[0, :, pl.ds(s * dilation, wblk)].astype(jnp.float32))
+    return acc
+
+
 def _dw_fwd_kernel(*refs, S: int, dilation: int, wblk: int, activation: str,
                    has_bias: bool, has_residual: bool, save_preact: bool):
-    """x_ref: (1, CB, F), w_ref: (S, CB), [b_ref: (CB, 1)],
-    [r_ref: (1, CB, WBLK)], o_ref: (1, CB, WBLK), [u_ref].  VPU fma chain."""
+    """x_ref: (1, CB, Fp), w_ref: (CB, S), [b_ref: (CB, 1)],
+    [r_ref: (1, CB, WBLK)], o_ref: (1, CB, WBLK), [u_ref]."""
     it = iter(refs)
     x_ref, w_ref = next(it), next(it)
     b_ref = next(it) if has_bias else None
@@ -782,11 +822,7 @@ def _dw_fwd_kernel(*refs, S: int, dilation: int, wblk: int, activation: str,
     o_ref = next(it)
     u_ref = next(it) if save_preact else None
 
-    x = x_ref[0]
-    acc = jnp.zeros((x_ref.shape[1], wblk), jnp.float32)
-    for s in range(S):
-        b = jax.lax.dynamic_slice_in_dim(x, s * dilation, wblk, axis=1)
-        acc += w_ref[s][:, None].astype(jnp.float32) * b.astype(jnp.float32)
+    acc = _dw_acc(x_ref, w_ref, S=S, dilation=dilation, wblk=wblk)
     u, y = _epilogue_on_acc(acc, b_ref,
                             r_ref[0] if has_residual else None, activation)
     if save_preact:
@@ -796,7 +832,7 @@ def _dw_fwd_kernel(*refs, S: int, dilation: int, wblk: int, activation: str,
 
 def _dw_fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, cblk: int,
                         activation: str, has_bias: bool, has_residual: bool,
-                        save_preact: bool, pipe: int, q_tiles: int,
+                        save_preact: bool, pipe: int, q_tiles: int, Fp: int,
                         sync: bool):
     """Software-pipelined ``_dw_fwd_kernel``: same rotation/streaming as
     the dense forward, on (1, cblk, ·) tiles of the VPU fma chain."""
@@ -809,20 +845,16 @@ def _dw_fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, cblk: int,
     xbuf, xsem, obuf, osem = next(it), next(it), next(it), next(it)
 
     n, ct, qt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    F = wblk + (S - 1) * dilation
 
     def x_copy(t):
         return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(n, 1), pl.ds(ct * cblk, cblk), pl.ds(t * wblk, F)],
+            x_hbm.at[pl.ds(n, 1), pl.ds(ct * cblk, cblk),
+                     pl.ds(_lane_offset(t, wblk), Fp)],
             xbuf.at[t % pipe], xsem.at[t % pipe])
 
     _pipe_schedule(qt, q_tiles, pipe, x_copy, sync)
-    x = xbuf[qt % pipe][0]                     # (cblk, F), staged
-
-    acc = jnp.zeros((cblk, wblk), jnp.float32)
-    for s in range(S):
-        b = jax.lax.dynamic_slice_in_dim(x, s * dilation, wblk, axis=1)
-        acc += w_ref[s][:, None].astype(jnp.float32) * b.astype(jnp.float32)
+    acc = _dw_acc(xbuf.at[qt % pipe], w_ref, S=S, dilation=dilation,
+                  wblk=wblk)
     u, y = _epilogue_on_acc(acc, b_ref,
                             r_ref[0] if has_residual else None, activation)
     if save_preact:
@@ -832,7 +864,7 @@ def _dw_fwd_kernel_pipe(*refs, S: int, dilation: int, wblk: int, cblk: int,
         return pltpu.make_async_copy(
             obuf.at[t % 2],
             o_hbm.at[pl.ds(n, 1), pl.ds(ct * cblk, cblk),
-                     pl.ds(t * wblk, wblk)],
+                     pl.ds(_lane_offset(t, wblk), wblk)],
             osem.at[t % 2])
 
     _store_wait_slot(qt, o_copy, sync)
@@ -854,6 +886,7 @@ def depthwise_conv1d_fwd(
     pipe: int = 0,
     out_dtype=None,
     interpret: bool = False,
+    name: str = "dwconv1d_fwd",
 ):
     """Depthwise forward.  x: (N, C, Qp+(S-1)d), w: (S, C) -> (N, C, Qp).
 
@@ -863,58 +896,61 @@ def depthwise_conv1d_fwd(
     N, C, Wp = x.shape
     S, Cw = w.shape
     assert C == Cw
-    F = wblk + (S - 1) * dilation
     Qp = Wp - (S - 1) * dilation
     assert Qp % wblk == 0
     cblk = cblk or default_cblk(C)
     assert C % cblk == 0, (C, cblk)
-    grid = (N, C // cblk, Qp // wblk)
     out_dtype = out_dtype or x.dtype
+    _check_tiling(interpret, wblk, cblk=(cblk, x.dtype),
+                  out_cblk=(cblk, out_dtype))
+    Fp = footprint(wblk, S, dilation)
+    x = _stage_width(x, Qp, wblk, Fp)
+    grid = (N, C // cblk, Qp // wblk)
     activation = canon(activation)
-    pipe = canon_pipe(pipe) if pltpu is not None else 0
+    pipe = canon_pipe(pipe)
 
+    tile = pl.BlockSpec((1, cblk, wblk), lambda n, ct, qt: (n, ct, qt))
+    # channel-major taps: the kernel reads one (cblk, 1) column per tap
+    w_spec = pl.BlockSpec((cblk, S), lambda n, ct, qt: (ct, 0))
     if pipe:
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec((S, cblk), lambda n, ct, qt: (0, ct))]
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
+        y_spec = pl.BlockSpec(memory_space=pl.ANY)
         dims = ("parallel", "parallel", "arbitrary")
     else:
-        in_specs = [
-            _overlap_spec((1, cblk, F), lambda n, ct, qt: (n, ct, qt * wblk)),
-            pl.BlockSpec((S, cblk), lambda n, ct, qt: (0, ct)),
-        ]
+        x_spec = _footprint_spec((1, cblk, Fp), lambda n, ct, qt: (
+            n, ct * cblk, _lane_offset(qt, wblk)))
+        y_spec = tile
         dims = ("parallel", "parallel", "parallel")
-    inputs = [x, w]
+    in_specs = [x_spec, w_spec]
+    inputs = [x, w.T]
     if bias is not None:
         assert bias.shape == (C,), (bias.shape, C)
         in_specs.append(pl.BlockSpec((cblk, 1), lambda n, ct, qt: (ct, 0)))
         inputs.append(bias.reshape(C, 1))
     if residual is not None:
         assert residual.shape == (N, C, Qp), (residual.shape, (N, C, Qp))
-        in_specs.append(pl.BlockSpec((1, cblk, wblk), lambda n, ct, qt: (n, ct, qt)))
+        in_specs.append(tile)
         inputs.append(residual)
 
-    out_spec = pl.BlockSpec((1, cblk, wblk), lambda n, ct, qt: (n, ct, qt))
-    out_specs = [pl.BlockSpec(memory_space=pltpu.ANY) if pipe else out_spec]
+    out_specs = [y_spec]
     out_shape = [jax.ShapeDtypeStruct((N, C, Qp), out_dtype)]
     if save_preact:
-        out_specs.append(out_spec)
+        out_specs.append(tile)
         out_shape.append(jax.ShapeDtypeStruct((N, C, Qp), jnp.float32))
 
+    common = dict(S=S, dilation=dilation, wblk=wblk, activation=activation,
+                  has_bias=bias is not None,
+                  has_residual=residual is not None, save_preact=save_preact)
     if pipe:
         kernel = functools.partial(
-            _dw_fwd_kernel_pipe, S=S, dilation=dilation, wblk=wblk, cblk=cblk,
-            activation=activation, has_bias=bias is not None,
-            has_residual=residual is not None, save_preact=save_preact,
-            pipe=pipe, q_tiles=Qp // wblk, sync=_sync_staging(interpret))
-        scratch = [pltpu.VMEM((pipe, 1, cblk, F), x.dtype),
+            _dw_fwd_kernel_pipe, cblk=cblk, pipe=pipe, q_tiles=Qp // wblk,
+            Fp=Fp, sync=_sync_staging(interpret), **common)
+        scratch = [pltpu.VMEM((pipe, 1, cblk, Fp), x.dtype),
                    pltpu.SemaphoreType.DMA((pipe,)),
                    pltpu.VMEM((2, 1, cblk, wblk), out_dtype),
                    pltpu.SemaphoreType.DMA((2,))]
     else:
-        kernel = functools.partial(
-            _dw_fwd_kernel, S=S, dilation=dilation, wblk=wblk,
-            activation=activation, has_bias=bias is not None,
-            has_residual=residual is not None, save_preact=save_preact)
+        kernel = functools.partial(_dw_fwd_kernel, **common)
         scratch = []
 
     return pl.pallas_call(
@@ -924,9 +960,19 @@ def depthwise_conv1d_fwd(
         out_specs=out_specs if save_preact else out_specs[0],
         out_shape=out_shape if save_preact else out_shape[0],
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(dims, interpret),
+        compiler_params=_compiler_params(dims),
         interpret=interpret,
+        name=name,
     )(*inputs)
+
+
+def _dw_bwd_w_accumulate(o_ref, g, x_ref, *, S: int, dilation: int,
+                         wblk: int):
+    """o_ref (CB, S) += per-tap width reductions of g * tap, one (CB, 1)
+    column per tap."""
+    for s in range(S):
+        b = x_ref[0, :, pl.ds(s * dilation, wblk)].astype(jnp.float32)
+        o_ref[:, pl.ds(s, 1)] += jnp.sum(g * b, axis=-1, keepdims=True)
 
 
 def _dw_bwd_w_kernel(x_ref, g_ref, o_ref, *dbias_ref, S: int, dilation: int,
@@ -939,18 +985,15 @@ def _dw_bwd_w_kernel(x_ref, g_ref, o_ref, *dbias_ref, S: int, dilation: int,
         if with_dbias:
             dbias_ref[0][...] = jnp.zeros_like(dbias_ref[0])
 
-    x = x_ref[0]
     g = g_ref[0].astype(jnp.float32)  # (CB, WBLK)
-    for s in range(S):
-        b = jax.lax.dynamic_slice_in_dim(x, s * dilation, wblk, axis=1)
-        o_ref[s] += jnp.sum(g * b.astype(jnp.float32), axis=-1)
+    _dw_bwd_w_accumulate(o_ref, g, x_ref, S=S, dilation=dilation, wblk=wblk)
     if with_dbias:
         dbias_ref[0][...] += jnp.sum(g, axis=-1, keepdims=True)
 
 
 def _dw_bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, cblk: int,
                           with_dbias: bool, pipe: int, nq: int, nc: int,
-                          total: int, sync: bool):
+                          total: int, Fp: int, sync: bool):
     """Software-pipelined ``_dw_bwd_w_kernel``: footprint + cotangent tiles
     rotate on the flattened (n·nq + qt)·nc + ct sequential step."""
     it = iter(refs)
@@ -959,7 +1002,6 @@ def _dw_bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, cblk: int,
     dbias_ref = next(it) if with_dbias else None
     xbuf, xsem, gbuf, gsem = next(it), next(it), next(it), next(it)
 
-    F = wblk + (S - 1) * dilation
     step = ((pl.program_id(0) * nq + pl.program_id(1)) * nc
             + pl.program_id(2))
     first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
@@ -971,26 +1013,24 @@ def _dw_bwd_w_kernel_pipe(*refs, S: int, dilation: int, wblk: int, cblk: int,
         return _MultiCopy([
             pltpu.make_async_copy(
                 x_hbm.at[pl.ds(n, 1), pl.ds(ci * cblk, cblk),
-                         pl.ds(qi * wblk, F)],
+                         pl.ds(_lane_offset(qi, wblk), Fp)],
                 xbuf.at[slot], xsem.at[slot]),
             pltpu.make_async_copy(
                 g_hbm.at[pl.ds(n, 1), pl.ds(ci * cblk, cblk),
-                         pl.ds(qi * wblk, wblk)],
+                         pl.ds(_lane_offset(qi, wblk), wblk)],
                 gbuf.at[slot], gsem.at[slot])])
 
     _pipe_schedule(step, total, pipe, copies, sync)
 
-    @pl.when(first)  # each (S, cblk) block zeroed at its first visit
+    @pl.when(first)  # each (cblk, S) block zeroed at its first visit
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
         if with_dbias:
             dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
-    x = xbuf[step % pipe][0]
-    g = gbuf[step % pipe][0].astype(jnp.float32)  # (CB, WBLK)
-    for s in range(S):
-        b = jax.lax.dynamic_slice_in_dim(x, s * dilation, wblk, axis=1)
-        o_ref[s] += jnp.sum(g * b.astype(jnp.float32), axis=-1)
+    g = gbuf[step % pipe, 0].astype(jnp.float32)  # (CB, WBLK)
+    _dw_bwd_w_accumulate(o_ref, g, xbuf.at[step % pipe], S=S,
+                         dilation=dilation, wblk=wblk)
     if with_dbias:
         dbias_ref[...] += jnp.sum(g, axis=-1, keepdims=True)
 
@@ -1006,6 +1046,7 @@ def depthwise_conv1d_bwd_weight(
     pipe: int = 0,
     with_dbias: bool = False,
     interpret: bool = False,
+    name: str = "dwconv1d_bwd_weight",
 ):
     """Depthwise weight gradient -> (S, C) fp32.
 
@@ -1015,14 +1056,19 @@ def depthwise_conv1d_bwd_weight(
     N, C, Wp = x.shape
     Ng, Cg, Qp = gout.shape
     assert N == Ng and C == Cg and Qp % wblk == 0
-    F = wblk + (S - 1) * dilation
     cblk = cblk or default_cblk(C)
     assert C % cblk == 0
+    _check_tiling(interpret, wblk, cblk=(cblk, x.dtype),
+                  g_cblk=(cblk, gout.dtype))
+    Fp = footprint(wblk, S, dilation)
+    x = _stage_width(x, Qp, wblk, Fp)
     grid = (N, Qp // wblk, C // cblk)
-    pipe = canon_pipe(pipe) if pltpu is not None else 0
+    pipe = canon_pipe(pipe)
 
-    out_specs = pl.BlockSpec((S, cblk), lambda n, qt, ct: (0, ct))
-    out_shape = jax.ShapeDtypeStruct((S, C), jnp.float32)
+    # channel-major (C, S) gradient: the kernel accumulates one (cblk, 1)
+    # column per tap; transposed back to (S, C) below
+    out_specs = pl.BlockSpec((cblk, S), lambda n, qt, ct: (ct, 0))
+    out_shape = jax.ShapeDtypeStruct((C, S), jnp.float32)
     if with_dbias:
         out_specs = [out_specs, pl.BlockSpec((cblk, 1), lambda n, qt, ct: (ct, 0))]
         out_shape = [out_shape, jax.ShapeDtypeStruct((C, 1), jnp.float32)]
@@ -1032,10 +1078,10 @@ def depthwise_conv1d_bwd_weight(
         kernel = functools.partial(
             _dw_bwd_w_kernel_pipe, S=S, dilation=dilation, wblk=wblk,
             cblk=cblk, with_dbias=with_dbias, pipe=pipe, nq=nq, nc=nc,
-            total=N * nq * nc, sync=_sync_staging(interpret))
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY)]
-        scratch = [pltpu.VMEM((pipe, 1, cblk, F), x.dtype),
+            total=N * nq * nc, Fp=Fp, sync=_sync_staging(interpret))
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)]
+        scratch = [pltpu.VMEM((pipe, 1, cblk, Fp), x.dtype),
                    pltpu.SemaphoreType.DMA((pipe,)),
                    pltpu.VMEM((pipe, 1, cblk, wblk), gout.dtype),
                    pltpu.SemaphoreType.DMA((pipe,))]
@@ -1044,7 +1090,8 @@ def depthwise_conv1d_bwd_weight(
             _dw_bwd_w_kernel, S=S, dilation=dilation, wblk=wblk,
             with_dbias=with_dbias)
         in_specs = [
-            _overlap_spec((1, cblk, F), lambda n, qt, ct: (n, ct, qt * wblk)),
+            _footprint_spec((1, cblk, Fp), lambda n, qt, ct: (
+                n, ct * cblk, _lane_offset(qt, wblk))),
             pl.BlockSpec((1, cblk, wblk), lambda n, qt, ct: (n, ct, qt)),
         ]
         scratch = []
@@ -1056,10 +1103,11 @@ def depthwise_conv1d_bwd_weight(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(("arbitrary", "arbitrary", "arbitrary"), interpret),
+        compiler_params=_compiler_params(("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(x, gout)
+    dw, db = out if with_dbias else (out, None)
     if with_dbias:
-        dw, db = out
-        return dw, db.reshape(C)
-    return out
+        return dw.T, db.reshape(C)
+    return dw.T

@@ -73,13 +73,18 @@ from .. import obs as _obs
 
 Padding = Literal["VALID", "SAME", "CAUSAL"]
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 # Per-channel-row VMEM footprint cap for the static tile ladder: one width
 # tile stages F = WBLK + (S-1)*d elements per channel row (16 KiB fp32 at
 # 4096).  ``repro.tune.space`` imports this so the tuner's legality filter
 # and the untuned ladder agree on what "fits".
 MAX_FOOTPRINT_ELEMS = 4096
+
+
+def _interpret_default() -> bool:
+    """Pallas runs compiled on a TPU and interpreted everywhere else —
+    decided per call from the platform the program is built for, never at
+    import."""
+    return jax.default_backend() != "tpu"
 
 
 def default_backend() -> str:
@@ -114,16 +119,13 @@ def _obs_conv(pass_: str, thunk, *, args, flops, attrs):
         return thunk()
 
     def _close(dur: float) -> dict:
-        out = {"flops": flops,
-               "gflops_per_s": flops / max(dur, 1e-30) / 1e9}
-        try:
-            from repro.obs.provenance import provenance
-            from repro.roofline.analysis import achieved_fraction_of_peak
-            out["efficiency"] = achieved_fraction_of_peak(
-                flops, dur, provenance()["device_kind"])
-        except Exception:
-            pass  # unknown device: report raw GFLOP/s only
-        return out
+        from repro.obs.provenance import provenance
+        from repro.roofline.analysis import achieved_fraction_of_peak
+        # an unknown device_kind raises here rather than reporting a guess
+        return {"flops": flops,
+                "gflops_per_s": flops / max(dur, 1e-30) / 1e9,
+                "efficiency": achieved_fraction_of_peak(
+                    flops, dur, provenance()["device_kind"])}
 
     with _obs.span(f"conv1d.{pass_}", close_attrs=_close, **attrs):
         out = thunk()
@@ -239,6 +241,64 @@ def _legal_nblk(nblk: int | None, N: int) -> int:
     (including a tuned nblk applied to a different batch at trace time)
     falls back to the unfolded kernel."""
     return nblk if nblk and N % nblk == 0 else 1
+
+
+def _pad_axis(a, axis: int, size: int):
+    """Zero-pad ``a`` along ``axis`` up to ``size`` (None passes through)."""
+    if a is None or a.shape[axis] == size:
+        return a
+    pads = [(0, 0)] * a.ndim
+    pads[axis] = (0, size - a.shape[axis])
+    return jnp.pad(a, pads)
+
+
+def _kernel_pass(pass_: str, x, b, *, depthwise: bool = False, bias=None,
+                 residual=None, kblk=None, cblk=None, out_dtype=None, **kw):
+    """Run one Pallas pass with the channel and filter dims zero-padded to
+    the sublane tile of the widest-tiled dtype involved (8 rows for fp32,
+    16 for bf16), then slice the results back.
+
+    Zero input channels add exact zeros to every contraction and zero
+    filters only produce rows that are dropped, so results are unchanged;
+    the padding is what lets the AtacWorks layers (C=K=15, the stem's
+    C_in=1, the heads' K=1) compile for Mosaic.  ``b`` is the weight for
+    fwd/bwd_data and the cotangent for bwd_weight.  A kblk/cblk that does
+    not tile the padded dim falls back to a legal one."""
+    dtypes = [x.dtype, b.dtype] + [a.dtype for a in (residual,)
+                                   if a is not None]
+    if out_dtype is not None:
+        dtypes.append(out_dtype)
+    sub = max(_k.sublane_tile(d) for d in dtypes)
+    C = x.shape[1]
+    Cp = _round_up(C, sub)
+    x = _pad_axis(x, 1, Cp)
+
+    def tile(blk, n):
+        return blk if blk and n % blk == 0 and blk % sub == 0 else None
+
+    if depthwise:
+        kw["cblk"] = tile(cblk, Cp) or _k.default_cblk(Cp, align=sub)
+    if pass_ == "bwd_weight":
+        n = b.shape[1]
+        out = _k.conv1d_pass(pass_, x, _pad_axis(b, 1, _round_up(n, sub)),
+                             depthwise=depthwise, **kw)
+        dw, db = out if isinstance(out, tuple) else (out, None)
+        dw = dw[:, :C] if depthwise else dw[:, :n, :C]
+        return dw if db is None else (dw, db[:n])
+    if depthwise:
+        n, n_pad, w = C, Cp, _pad_axis(b, 1, Cp)
+    else:
+        n = b.shape[1]
+        n_pad = _round_up(n, sub)
+        w = _pad_axis(_pad_axis(b, 2, Cp), 1, n_pad)
+        kw["kblk"] = tile(kblk, n_pad) or n_pad
+    out = _k.conv1d_pass(pass_, x, w, depthwise=depthwise,
+                         bias=_pad_axis(bias, 0, n_pad),
+                         residual=_pad_axis(residual, 1, n_pad),
+                         out_dtype=out_dtype, **kw)
+    if isinstance(out, tuple):
+        return tuple(o[:, :n] for o in out)
+    return out[:, :n]
 
 
 def _pipe_attrs(pipe, *, pass_, N, C, K, S, dilation, Q, dtype, depthwise,
@@ -469,9 +529,9 @@ def _plain_fwd_padded(x, w, dilation, wblk, kblk, interpret,
     Qp = _round_up(Q, wblk)
     if Qp + span > W:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, Qp + span - W)))
-    out = _k.conv1d_pass(pass_, x, w, dilation=dilation, wblk=wblk,
-                         kblk=kblk, alg=alg, nblk=_legal_nblk(nblk, N),
-                         pipe=pipe, interpret=interpret)
+    out = _kernel_pass(pass_, x, w, dilation=dilation, wblk=wblk,
+                       kblk=kblk, alg=alg, nblk=_legal_nblk(nblk, N),
+                       pipe=pipe, interpret=interpret)
     return out[:, :, :Q]
 
 
@@ -489,7 +549,7 @@ def _fused_fwd_padded(spec: _FusedSpec, x, w, bias, residual,
         x = jnp.pad(x, ((0, 0), (0, 0), (0, Qp + span - W)))
     if residual is not None and Qp > Q:
         residual = jnp.pad(residual, ((0, 0), (0, 0), (0, Qp - Q)))
-    out = _k.conv1d_pass(
+    out = _kernel_pass(
         "fwd", x, w, bias=bias, residual=residual, activation=spec.activation,
         save_preact=save_preact, dilation=spec.dilation, wblk=spec.wblk,
         kblk=spec.blk2, alg=spec.alg, nblk=spec.nblk, pipe=spec.pipe,
@@ -697,7 +757,7 @@ def _conv1d_pallas_bwd(spec, res, gout):
         def bw_range(a, b):
             # width-tile-aligned slice: chunk boundaries are [lo, hi) in
             # units of wblk tiles, so every chunk keeps the kernel's tiling
-            return _k.conv1d_pass(
+            return _kernel_pass(
                 "bwd_weight", xp[:, :, a * wblk:b * wblk + span],
                 gp[:, :, a * wblk:b * wblk], S=S, dilation=d, wblk=wblk,
                 alg=bw_alg, nblk=bw_nblk, pipe=bw_pipe,
@@ -873,7 +933,7 @@ def conv1d(
             return u.astype(out_dtype or x.dtype)
     elif backend == "pallas":
         wblk = wblk or pick_wblk(Q, S, dilation)
-        interpret = _INTERPRET if interpret is None else interpret
+        interpret = _interpret_default() if interpret is None else interpret
         spec = _FusedSpec(dilation, wblk, kblk, interpret, activation,
                           _dtype_name(bias), _dtype_name(residual),
                           jnp.dtype(out_dtype).name if out_dtype else None,
@@ -1028,9 +1088,8 @@ def _dw_plain_fwd_padded(x, w, dilation, wblk, cblk, interpret,
     Qp = _round_up(Q, wblk)
     if Qp + span > W:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, Qp + span - W)))
-    out = _k.conv1d_pass(pass_, x, w, depthwise=True, dilation=dilation,
-                         wblk=wblk, cblk=cblk, pipe=pipe,
-                         interpret=interpret)
+    out = _kernel_pass(pass_, x, w, depthwise=True, dilation=dilation,
+                       wblk=wblk, cblk=cblk, pipe=pipe, interpret=interpret)
     return out[:, :, :Q]
 
 
@@ -1045,7 +1104,7 @@ def _dw_fused_fwd_padded(spec: _FusedSpec, x, w, bias, residual,
         x = jnp.pad(x, ((0, 0), (0, 0), (0, Qp + span - W)))
     if residual is not None and Qp > Q:
         residual = jnp.pad(residual, ((0, 0), (0, 0), (0, Qp - Q)))
-    out = _k.conv1d_pass(
+    out = _kernel_pass(
         "fwd", x, w, depthwise=True, bias=bias, residual=residual,
         activation=spec.activation, save_preact=save_preact,
         dilation=spec.dilation, wblk=spec.wblk, cblk=spec.blk2,
@@ -1144,7 +1203,7 @@ def _dw_conv1d_pallas_bwd(spec, res, gout):
         bw_pipe = _k.canon_pipe(bw.pipe)
 
         def bw_range(a, b):
-            return _k.conv1d_pass(
+            return _kernel_pass(
                 "bwd_weight", xp[:, :, a * wblk:b * wblk + span],
                 gp[:, :, a * wblk:b * wblk], depthwise=True, S=S,
                 dilation=d, wblk=wblk, cblk=cblk, pipe=bw_pipe,
@@ -1283,7 +1342,7 @@ def depthwise_conv1d(
             return u.astype(out_dtype or x.dtype)
     elif backend == "pallas":
         wblk = wblk or pick_wblk(Q, S, dilation)
-        interpret = _INTERPRET if interpret is None else interpret
+        interpret = _interpret_default() if interpret is None else interpret
         spec = _FusedSpec(dilation, wblk, cblk, interpret, activation,
                           _dtype_name(bias), _dtype_name(residual),
                           jnp.dtype(out_dtype).name if out_dtype else None,

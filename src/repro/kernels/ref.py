@@ -3,6 +3,10 @@
 These are the ground truth the Pallas kernels are tested against
 (``tests/test_kernels_conv1d.py`` sweeps shapes/dtypes and asserts allclose).
 
+The einsums run at ``Precision.HIGHEST``: on a TPU the default matmul
+precision takes bf16 passes, which would make the oracle too loose to
+check an fp32 kernel against.
+
 Conventions (paper layout, Section 2):
   x      : (N, C, W)   input,  N batch, C channels, W width
   w      : (S, K, C)   weights in the paper's *forward* layout (Alg. 1/2)
@@ -29,8 +33,8 @@ def _conv1d_f32(x: jax.Array, w: jax.Array, dilation: int) -> jax.Array:
     for s in range(S):
         xs = jax.lax.dynamic_slice_in_dim(x, s * dilation, Q, axis=2)
         out = out + jnp.einsum(
-            "kc,ncq->nkq", w[s].astype(jnp.float32), xs.astype(jnp.float32)
-        )
+            "kc,ncq->nkq", w[s].astype(jnp.float32), xs.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
     return out
 
 
@@ -82,7 +86,8 @@ def conv1d_bwd_weight_ref(
     taps = []
     for s in range(S):
         xs = jax.lax.dynamic_slice_in_dim(x, s * dilation, Q, axis=2)
-        taps.append(jnp.einsum("nkq,ncq->kc", g32, xs.astype(jnp.float32)))
+        taps.append(jnp.einsum("nkq,ncq->kc", g32, xs.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
     return jnp.stack(taps, axis=0)  # (S, K, C) fp32
 
 

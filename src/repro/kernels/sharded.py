@@ -50,8 +50,9 @@ the above on a 2D ``(data, model)`` mesh:
     its docstring for why jax's default reduce-scatter transpose would
     double-count here).
 
-``shard_map`` is used with ``check_rep=False`` (required for bodies
-containing custom_vjp calls on jax 0.4.x).
+``shard_map`` is used with ``check_vma=False``: the psums inside the
+custom VJPs establish replication, which the varying-axes check does not
+follow through a custom_vjp.
 
 Example (single host; any device count divides the batch)::
 
@@ -74,7 +75,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import MP_AXIS, dp_axis_names, dp_size
@@ -121,7 +122,7 @@ def _sharded_call(fn, mesh, x, w, bias, residual, kwargs):
         return fn(a[0], a[1], bias=b, residual=r, **kwargs)
 
     return shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=batch, check_rep=False)(*args)
+                     out_specs=batch, check_vma=False)(*args)
 
 
 def sharded_conv1d(x, w, *, mesh, bias=None, residual=None, **kwargs):
@@ -283,7 +284,7 @@ def _model_sharded_call(fn, mesh, x, w, bias, residual, kwargs, *,
         return fn(a[0], a[1], bias=b, residual=r, **kwargs)
 
     return shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=out, check_rep=False)(*args)
+                     out_specs=out, check_vma=False)(*args)
 
 
 def model_sharded_conv1d(x, w, *, mesh, bias=None, residual=None, **kwargs):

@@ -21,14 +21,11 @@ import jax
 
 
 def compat_make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (``axis_types=`` and ``jax.sharding.AxisType`` arrived
-    after 0.4.x; older jax treats every axis as Auto already)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with every axis typed Auto (GSPMD propagation), the
+    sharding mode all of this repo's rules are written for."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

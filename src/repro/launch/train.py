@@ -150,11 +150,16 @@ def _build_state(model, cfg, mesh, seed):
     template: the checkpoint stores mesh-agnostic whole arrays, placement
     happens against whatever this mesh prescribes."""
     params = model.init_params(jax.random.key(seed), cfg)
-    pspecs = shd.param_pspecs(params, mesh)
-    params = jax.tree.map(
-        lambda p, s: jax.device_put(p, jax.sharding.NamedSharding(mesh, s)),
-        params, pspecs)
-    return init_state(params)
+    psh = jax.tree.map(lambda s: jax.sharding.NamedSharding(mesh, s),
+                       shd.param_pspecs(params, mesh))
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    state = init_state(jax.device_put(params, psh))
+    # the optimizer moments sit like their params and the counters
+    # replicated, as the step returns them: step 0's inputs then have the
+    # shardings of every later step's, and the step compiles once
+    return jax.device_put(state, state._replace(
+        params=psh, opt=state.opt._replace(m=psh, v=psh, count=rep),
+        step=rep))
 
 
 def run(argv=None) -> dict:
@@ -561,4 +566,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     raise SystemExit(main())

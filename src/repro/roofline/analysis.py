@@ -62,21 +62,31 @@ class Peaks:
     bytes_per_s: float
 
 
-# Coarse per-device peaks; matched by substring of jax's device_kind.
+# Per-chip peaks keyed by the exact ``device_kind`` JAX reports.  TPU
+# figures: Google Cloud TPU documentation, system architecture pages
+# ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e"): dense bf16 matmul FLOP/s and
+# HBM bandwidth per chip.  "cpu" is not a published peak: it is a nominal
+# figure for the host CPU, so the tuner's cost model can rank candidates
+# off-chip; it is never a device metric.
 DEVICE_PEAKS = {
-    "v5": Peaks(197e12, 819e9),     # TPU v5e (bf16 MXU)
-    "v4": Peaks(275e12, 1200e9),
-    "tpu": Peaks(180e12, 800e9),    # generic TPU fallback
-    "cpu": Peaks(1e11, 5e10),       # container CPU fallback
+    "TPU v4": Peaks(275e12, 1200e9),
+    "TPU v5 lite": Peaks(197e12, 819e9),     # TPU v5e
+    "TPU v5": Peaks(459e12, 2765e9),          # TPU v5p
+    "TPU v6 lite": Peaks(918e12, 1640e9),     # TPU v6e (Trillium)
+    "cpu": Peaks(1e11, 5e10),
 }
 
 
 def peaks_for(device_kind: str) -> Peaks:
-    dk = device_kind.lower()
-    for sub, p in DEVICE_PEAKS.items():
-        if sub in dk:
-            return p
-    return DEVICE_PEAKS["cpu"]
+    """The roofline peaks of ``device_kind``; a kind not in
+    ``DEVICE_PEAKS`` is an error, never a guess."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no roofline peaks for device_kind {device_kind!r}; add its "
+            f"published figures to DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)})") from None
 
 
 def achieved_fraction_of_peak(flops: float, sec: float,
@@ -221,8 +231,6 @@ def hlo_traffic_bytes(hlo_text: str) -> float:
 def compile_metrics(compiled) -> dict[str, Any]:
     """flops / bytes / collective bytes of one compiled per-device module."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older API returned [dict]
-        cost = cost[0]
     text = compiled.as_text()
     coll = collective_bytes(text)
     return {

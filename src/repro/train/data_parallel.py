@@ -39,7 +39,7 @@ chunks) inside its custom VJP.
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -121,9 +121,10 @@ def make_sharded_grad_fn(cfg, mesh, *, loss_fn=None, grad_reduce_chunks=None,
 
     # replicate params, shard every batch leaf on its leading dim; grads/
     # metrics come out replicated (identical post-psum on every shard).
-    # check_rep=False: the body contains custom_vjp calls (unsupported by
-    # 0.4.x rep checking); replication is established by the psums above.
+    # check_vma=False: replication is established by the psums above and
+    # inside the conv custom VJPs, which the varying-axes check does not
+    # follow.
     return shard_map(local_grad, mesh=mesh,
                      in_specs=(P(), P(axes)),
                      out_specs=((P(), P()), P()),
-                     check_rep=False)
+                     check_vma=False)

@@ -94,7 +94,7 @@ def make_phase_probes(cfg, *, mesh=None, lr: float = 1e-4,
 
     psum_jit = None
     if mesh is not None and _mesh_dp(mesh) > 1:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import dp_axis_names
         axes = dp_axis_names(mesh)
@@ -104,7 +104,7 @@ def make_phase_probes(cfg, *, mesh=None, lr: float = 1e-4,
 
         psum_jit = jax.jit(shard_map(
             _psum_tree, mesh=mesh, in_specs=(P(),), out_specs=P(),
-            check_rep=False))
+            check_vma=False))
 
     def probe(state, batch, *, iters: int = 3, warmup: int = 1):
         t_fwd = median_time(fwd_jit, state.params, batch,

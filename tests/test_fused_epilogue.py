@@ -298,7 +298,7 @@ def test_space_and_cost_accept_epilogue():
 
     cands = space.enumerate_candidates(fused_prob)
     assert any(c.backend == "pallas" for c in cands)
-    est = cost.estimate_seconds(cands[0], fused_prob, device_kind="TPU v5e")
+    est = cost.estimate_seconds(cands[0], fused_prob, device_kind="TPU v5 lite")
     est_plain = cost.estimate_seconds(cands[0], plain_prob,
-                                      device_kind="TPU v5e")
+                                      device_kind="TPU v5 lite")
     assert est >= est_plain  # residual read traffic never makes it cheaper

@@ -183,7 +183,7 @@ os.environ.pop("REPRO_TUNE", None)
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro import tune
 from repro.kernels import ops
@@ -282,7 +282,7 @@ def dx_psum(chunks):
         return jax.grad(loss)(x)
     return shard_map(local, mesh=mesh12,
                      in_specs=(P(), P(None, "model", None)),
-                     out_specs=P(), check_rep=False)(xf, wf)
+                     out_specs=P(), check_vma=False)(xf, wf)
 out["chunked_vs_single_psum"] = bitdiff(dx_psum(4), dx_psum(1))
 
 # --- per-shard tuner plans resolve from LOCAL-K keys ---------------------

@@ -244,7 +244,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.kernels import ops
 from repro.launch.mesh import make_host_mesh, dp_axis_names
@@ -274,7 +274,7 @@ def sharded_grads(conv, weights, chunks):
         return jax.grad(loss)(ws)
     f = shard_map(body, mesh=mesh,
                   in_specs=(P(axes),) + (P(),) * len(weights),
-                  out_specs=(P(),) * len(weights), check_rep=False)
+                  out_specs=(P(),) * len(weights), check_vma=False)
     return jax.jit(f)(x, *weights)
 
 # dense + depthwise, fused bias epilogue: chunked psum (4-way over the

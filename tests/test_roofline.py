@@ -58,10 +58,7 @@ class TestScanPremise:
 
         s = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         c = jax.jit(f).lower(s, s).compile()
-        cost = c.cost_analysis()
-        if isinstance(cost, list):  # older API returned [dict]
-            cost = cost[0]
-        flops = cost.get("flops", 0.0)
+        flops = c.cost_analysis().get("flops", 0.0)
         one_matmul = 2 * 64 ** 3
         assert flops < 2.5 * one_matmul, (
             "XLA now multiplies while bodies by trip count — remove the "
@@ -120,3 +117,18 @@ class TestModelFlops:
         assert 2e9 < cfg.active_param_count() < 5.5e9
         assert 2e10 < cfg.param_count() < 3.2e10
         assert cfg.param_count() > 4 * cfg.active_param_count()
+
+
+class TestPeaks:
+    def test_exact_device_kind(self):
+        v5e = ra.peaks_for("TPU v5 lite")
+        assert (v5e.flops_per_s, v5e.bytes_per_s) == (197e12, 819e9)
+        # "TPU v5" is the v5p, not a prefix match of the v5e's kind
+        assert ra.peaks_for("TPU v5").flops_per_s != v5e.flops_per_s
+
+    def test_unknown_device_kind_raises(self):
+        import pytest
+
+        for kind in ("TPU v5e", "tpu", "TPU v7x"):
+            with pytest.raises(KeyError, match="no roofline peaks"):
+                ra.peaks_for(kind)

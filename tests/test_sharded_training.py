@@ -101,7 +101,7 @@ def test_grad_reduce_axes_in_body_matches_plain():
     """The train path's shape: value_and_grad INSIDE a shard_map body with
     grad_reduce_axes threaded — the fused psum is then the only reduction
     (on a 1-axis mesh of size 1 it must be an exact no-op)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_host_mesh()
@@ -118,7 +118,7 @@ def test_grad_reduce_axes_in_body_matches_plain():
         return jax.grad(loss)((w, b))
 
     sm = shard_map(local, mesh=mesh, in_specs=(P(axes), P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     gs = sm(x, w, b)
     g1 = jax.grad(lambda wb: (ops.conv1d(
         x, wb[0], bias=wb[1], activation="relu", dilation=2, padding="SAME",

@@ -358,7 +358,7 @@ def test_cost_prefers_packed_for_skinny_shapes_on_tpu():
     prob = _prob(Q=5000)
     cands = [c for c in space.enumerate_candidates(prob)
              if c.backend == "pallas"]
-    best = cost.rank(cands, prob, device_kind="TPU v5e")[0]
+    best = cost.rank(cands, prob, device_kind="TPU v5 lite")[0]
     assert best.alg == "tap_packed"
 
 
@@ -368,7 +368,7 @@ def test_cost_keeps_tap_loop_for_fat_shapes_on_tpu():
     prob = _prob(C=256, K=256, S=5, dilation=1, Q=5000)
     cands = [c for c in space.enumerate_candidates(prob)
              if c.backend == "pallas"]
-    best = cost.rank(cands, prob, device_kind="TPU v5e")[0]
+    best = cost.rank(cands, prob, device_kind="TPU v5 lite")[0]
     assert best.alg == "tap_loop"
 
 

@@ -170,7 +170,7 @@ def test_cost_model_wblk_never_shrinks_with_q():
                          alg="tap_loop", nblk=1, pipe=0)
             cands = [c for c in space.enumerate_candidates(prob)
                      if c.backend == "pallas"]
-            best = cost.rank(cands, prob, device_kind="TPU v5e")[0]
+            best = cost.rank(cands, prob, device_kind="TPU v5 lite")[0]
             assert best.wblk >= prev, (C, K, S, d, Q, best)
             assert best.wblk >= ops.pick_wblk(Q, S, d), (C, K, S, d, Q, best)
             prev = best.wblk
