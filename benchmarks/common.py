@@ -30,8 +30,8 @@ def bench_entry(sec: float, *, flops: float | None = None,
                 source: str = "", **extra) -> dict:
     """One benchmark row in the shared artifact schema: ``ms`` always;
     ``gflops``/``efficiency`` derived from ``flops`` when the row has a
-    FLOP count (paper-style efficiency, same roofline as telemetry's conv
-    spans); anything else rides along verbatim."""
+    FLOP count (paper-style efficiency against the device's roofline
+    peak); anything else rides along verbatim."""
     row = {"ms": sec * 1e3, "source": source, **extra}
     if flops is not None:
         row["gflops"] = flops / sec / 1e9

@@ -6,7 +6,7 @@
     PYTHONPATH=src python scripts/obs_report.py telemetry.jsonl --check
 
 ``--check`` is the CI smoke gate: nonzero exit unless the log contains
-measured conv1d efficiency, a train-step phase breakdown, and tuner cache
+conv1d pass config records, a train-step phase breakdown, and tuner cache
 counters.  See docs/observability.md.
 """
 import os

@@ -21,12 +21,25 @@ from __future__ import annotations
 
 import os
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.conv1d import DilatedConv1D
 from repro.models import common as cm
 
 N_RES_BLOCKS = 11  # 1 stem + 11*2 res + 2 heads = 25 conv layers
+
+# Layer scopes (``jax.named_scope``): every op a layer adds, forward and
+# backward, carries its scope in its HLO ``op_name`` and so in the device
+# trace.  Metadata only: the compiled program is the same without them.
+STEM = "layer.stem"
+HEAD_SIGNAL = "layer.head_signal"
+HEAD_PEAK = "layer.head_peak"
+
+
+def res_scope(block: int, conv: int) -> str:
+    """Scope of conv ``conv`` (1 or 2) of residual block ``block``."""
+    return f"layer.res{block}.conv{conv}"
 
 
 def init_params(key, cfg):
@@ -94,32 +107,37 @@ def forward(params, cfg, x, *, backend=None, fused=None, grad_reduce_axes=None,
     d = cfg.conv_dilation
     gra = grad_reduce_axes
     grc = grad_reduce_chunks
-    h = x[:, None, :]  # (B, 1, W)
-    h = DilatedConv1D.apply(params["stem"], h, dilation=d, backend=backend,
-                            padding=padding,
-                            activation="relu", grad_reduce_axes=gra,
-                            grad_reduce_chunks=grc)
-    for blk in params["res"]:
-        r = DilatedConv1D.apply(blk["conv1"], h, dilation=d, backend=backend,
-                                padding=padding,
+    with jax.named_scope(STEM):
+        h = x[:, None, :]  # (B, 1, W)
+        h = DilatedConv1D.apply(params["stem"], h, dilation=d,
+                                backend=backend, padding=padding,
                                 activation="relu", grad_reduce_axes=gra,
                                 grad_reduce_chunks=grc)
-        h = DilatedConv1D.apply(blk["conv2"], r, dilation=d, backend=backend,
-                                padding=padding,
-                                activation="relu", residual=h,
-                                grad_reduce_axes=gra,
-                                grad_reduce_chunks=grc)
-    signal = DilatedConv1D.apply(params["head_signal"], h, dilation=d,
-                                 backend=backend, activation="relu",
-                                 padding=padding,
-                                 out_dtype=jnp.float32,
-                                 grad_reduce_axes=gra,
-                                 grad_reduce_chunks=grc)[:, 0, :]
-    peak = DilatedConv1D.apply(params["head_peak"], h, dilation=d,
-                               backend=backend, out_dtype=jnp.float32,
-                               padding=padding,
-                               grad_reduce_axes=gra,
-                               grad_reduce_chunks=grc)[:, 0, :]
+    for i, blk in enumerate(params["res"]):
+        with jax.named_scope(res_scope(i, 1)):
+            r = DilatedConv1D.apply(blk["conv1"], h, dilation=d,
+                                    backend=backend, padding=padding,
+                                    activation="relu", grad_reduce_axes=gra,
+                                    grad_reduce_chunks=grc)
+        with jax.named_scope(res_scope(i, 2)):
+            h = DilatedConv1D.apply(blk["conv2"], r, dilation=d,
+                                    backend=backend, padding=padding,
+                                    activation="relu", residual=h,
+                                    grad_reduce_axes=gra,
+                                    grad_reduce_chunks=grc)
+    with jax.named_scope(HEAD_SIGNAL):
+        signal = DilatedConv1D.apply(params["head_signal"], h, dilation=d,
+                                     backend=backend, activation="relu",
+                                     padding=padding,
+                                     out_dtype=jnp.float32,
+                                     grad_reduce_axes=gra,
+                                     grad_reduce_chunks=grc)[:, 0, :]
+    with jax.named_scope(HEAD_PEAK):
+        peak = DilatedConv1D.apply(params["head_peak"], h, dilation=d,
+                                   backend=backend, out_dtype=jnp.float32,
+                                   padding=padding,
+                                   grad_reduce_axes=gra,
+                                   grad_reduce_chunks=grc)[:, 0, :]
     return signal, peak
 
 
@@ -175,17 +193,23 @@ def _forward_model_sharded(params, cfg, x, *, backend, padding,
     kw = dict(cfg=cfg, backend=backend, padding=padding, mp=mp,
               axis=model_axis, gra=grad_reduce_axes,
               grc=grad_reduce_chunks, mrc=model_reduce_chunks)
-    h = x[:, None, :]  # (B, 1, W)
     # stem: x is training data — nothing upstream needs dx, skip its psum
-    h = _mp_apply(params["stem"], h, activation="relu", input_grad=False,
-                  **kw)
-    for blk in params["res"]:
-        r = _mp_apply(blk["conv1"], h, activation="relu", **kw)
-        h = _mp_apply(blk["conv2"], r, activation="relu", residual=h, **kw)
-    signal = _mp_apply(params["head_signal"], h, activation="relu",
-                       out_dtype=jnp.float32, **kw)[:, 0, :]
-    peak = _mp_apply(params["head_peak"], h, out_dtype=jnp.float32,
-                     **kw)[:, 0, :]
+    with jax.named_scope(STEM):
+        h = x[:, None, :]  # (B, 1, W)
+        h = _mp_apply(params["stem"], h, activation="relu",
+                      input_grad=False, **kw)
+    for i, blk in enumerate(params["res"]):
+        with jax.named_scope(res_scope(i, 1)):
+            r = _mp_apply(blk["conv1"], h, activation="relu", **kw)
+        with jax.named_scope(res_scope(i, 2)):
+            h = _mp_apply(blk["conv2"], r, activation="relu", residual=h,
+                          **kw)
+    with jax.named_scope(HEAD_SIGNAL):
+        signal = _mp_apply(params["head_signal"], h, activation="relu",
+                           out_dtype=jnp.float32, **kw)[:, 0, :]
+    with jax.named_scope(HEAD_PEAK):
+        peak = _mp_apply(params["head_peak"], h, out_dtype=jnp.float32,
+                         **kw)[:, 0, :]
     return signal, peak
 
 
@@ -195,8 +219,6 @@ def forward_unfused(params, cfg, x, *, backend=None, grad_reduce_axes=None,
     residual-add as four separate XLA ops per layer.  Kept only as the
     fused-vs-unfused comparison arm of ``bench_atacworks_e2e`` — the model
     itself always trains through ``forward``."""
-    import jax
-
     from repro.kernels.ops import _axes_tuple, _psum_cotangent
 
     axes = _axes_tuple(grad_reduce_axes)
@@ -210,15 +232,23 @@ def forward_unfused(params, cfg, x, *, backend=None, grad_reduce_axes=None,
             b = _psum_cotangent(axes, b)
         return y + b[None, :, None].astype(y.dtype)
 
-    h = x[:, None, :]  # (B, 1, W)
-    h = jax.nn.relu(conv_bias(params["stem"], h).astype(jnp.float32)).astype(h.dtype)
-    for blk in params["res"]:
-        r = jax.nn.relu(conv_bias(blk["conv1"], h).astype(jnp.float32)).astype(h.dtype)
-        r = conv_bias(blk["conv2"], r)
-        h = jax.nn.relu((h + r).astype(jnp.float32)).astype(h.dtype)
-    signal = conv_bias(params["head_signal"], h)[:, 0, :]
-    peak = conv_bias(params["head_peak"], h)[:, 0, :]
-    return jax.nn.relu(signal.astype(jnp.float32)), peak.astype(jnp.float32)
+    with jax.named_scope(STEM):
+        h = x[:, None, :]  # (B, 1, W)
+        h = jax.nn.relu(conv_bias(params["stem"], h).astype(
+            jnp.float32)).astype(h.dtype)
+    for i, blk in enumerate(params["res"]):
+        with jax.named_scope(res_scope(i, 1)):
+            r = jax.nn.relu(conv_bias(blk["conv1"], h).astype(
+                jnp.float32)).astype(h.dtype)
+        with jax.named_scope(res_scope(i, 2)):
+            r = conv_bias(blk["conv2"], r)
+            h = jax.nn.relu((h + r).astype(jnp.float32)).astype(h.dtype)
+    with jax.named_scope(HEAD_SIGNAL):
+        signal = jax.nn.relu(
+            conv_bias(params["head_signal"], h)[:, 0, :].astype(jnp.float32))
+    with jax.named_scope(HEAD_PEAK):
+        peak = conv_bias(params["head_peak"], h)[:, 0, :].astype(jnp.float32)
+    return signal, peak
 
 
 def loss_fn(params, cfg, batch, *, backend=None, peak_weight: float = 1.0,
@@ -232,12 +262,13 @@ def loss_fn(params, cfg, batch, *, backend=None, peak_weight: float = 1.0,
                                   model_axis=model_axis,
                                   model_parallel=model_parallel,
                                   model_reduce_chunks=model_reduce_chunks)
-    mse = jnp.mean((signal - batch["clean"].astype(jnp.float32)) ** 2)
-    labels = batch["peaks"].astype(jnp.float32)
-    bce = jnp.mean(
-        jnp.maximum(peak_logits, 0) - peak_logits * labels
-        + jnp.log1p(jnp.exp(-jnp.abs(peak_logits))))
-    return mse + peak_weight * bce, {"mse": mse, "bce": bce}
+    with jax.named_scope("step.loss"):
+        mse = jnp.mean((signal - batch["clean"].astype(jnp.float32)) ** 2)
+        labels = batch["peaks"].astype(jnp.float32)
+        bce = jnp.mean(
+            jnp.maximum(peak_logits, 0) - peak_logits * labels
+            + jnp.log1p(jnp.exp(-jnp.abs(peak_logits))))
+        return mse + peak_weight * bce, {"mse": mse, "bce": bce}
 
 
 def init_stream_state(cfg, batch, dtype=jnp.float32):

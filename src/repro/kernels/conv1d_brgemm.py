@@ -102,6 +102,7 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 from typing import Sequence
 
 import jax
@@ -109,7 +110,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .epilogue import ACTIVATIONS, canon
+from .. import obs as _obs
+from .epilogue import ACTIVATIONS, canon, signature
 
 ALGS = ("tap_loop", "tap_packed")   # dense contraction formulations (§12)
 LANES = 128                          # TPU vreg lane count
@@ -276,6 +278,13 @@ def conv1d_pass(pass_: str, *args, depthwise: bool = False, **kw):
     configs resolved by ``repro.tune`` (wblk + kblk/cblk) arrive here as
     plain kwargs, so the tuner, the ops-layer VJP, and a direct caller all
     drive the same dispatch.
+
+    Each call is one kernel build: under jit it traces one ``pallas_call``
+    (Mosaic lowers it later, with the step).  It runs in a
+    ``kernels.build`` span and adds to the ``repro.obs`` counters
+    ``kernels.build``, ``kernels.build_s`` (seconds in the call) and
+    ``kernels.build_distinct`` (distinct signatures), all at trace time,
+    never inside a compiled step.
     """
     prefix = "dwconv1d" if depthwise else "conv1d"
     if pass_ == "bwd_weight":
@@ -284,7 +293,46 @@ def conv1d_pass(pass_: str, *args, depthwise: bool = False, **kw):
         fn = depthwise_conv1d_fwd if depthwise else conv1d_fwd
     else:
         raise ValueError(f"unknown conv pass {pass_!r}")
-    return fn(*args, name=f"{prefix}_{pass_}", **kw)
+    name = f"{prefix}_{pass_}"
+    attrs = _build_attrs(name, args, kw) if _obs.enabled() else {}
+    t0 = time.perf_counter()
+    with _obs.span("kernels.build", **attrs):
+        out = fn(*args, name=name, **kw)
+    _obs.counter("kernels.build")
+    _obs.counter("kernels.build_s", time.perf_counter() - t0)
+    _obs.distinct("kernels.build_distinct", _build_signature(name, args, kw))
+    return out
+
+
+_TILE_KEYS = ("wblk", "kblk", "cblk", "nblk", "alg", "pipe")
+
+
+def _operand(v):
+    """An argument as it enters a build signature: arrays by shape and
+    dtype, everything else as given."""
+    if hasattr(v, "shape") and hasattr(v, "dtype"):
+        return tuple(v.shape), jnp.dtype(v.dtype).name
+    return v
+
+
+def _build_signature(name: str, args, kw) -> tuple:
+    return (name, tuple(_operand(a) for a in args),
+            tuple(sorted((k, _operand(v)) for k, v in kw.items())))
+
+
+def _build_attrs(name: str, args, kw) -> dict:
+    """The ``kernels.build`` span's attrs: pass, operand shapes, dtype,
+    tiles and epilogue."""
+    if name.endswith("bwd_weight"):
+        epilogue = "dbias" if kw.get("with_dbias") else "none"
+    else:
+        epilogue = signature(kw.get("bias") is not None,
+                             kw.get("activation"),
+                             kw.get("residual") is not None)
+    return dict(kernel=name,
+                shapes=",".join("x".join(map(str, a.shape)) for a in args),
+                dtype=jnp.dtype(args[0].dtype).name, epilogue=epilogue,
+                **{k: kw[k] for k in _TILE_KEYS if k in kw})
 
 
 def _compiler_params(dimension_semantics: Sequence[str]):

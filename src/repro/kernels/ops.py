@@ -96,41 +96,17 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _obs_conv(pass_: str, thunk, *, args, flops, attrs):
-    """Run one conv1d pass under telemetry (repro.obs, DESIGN.md §14).
-
-    Telemetry is host-side only and must never change what gets compiled,
-    so the behaviour splits on whether the pass is being *traced*:
-
-      * concrete (eager) arguments — a timed ``conv1d.<pass>`` span:
-        ``block_until_ready`` wall time, plus the achieved fraction of the
-        roofline peak computed from ``flops`` at span close;
-      * tracer arguments (inside jit / vjp tracing) — a zero-duration
-        ``conv1d.<pass>.trace`` event recording the resolved config only.
-        No jnp ops are added either way, so enabling telemetry cannot
-        retrace or alter a jaxpr.
-
-    Disabled path is a single ``enabled()`` check before any dict is built.
-    """
-    if not _obs.enabled():
-        return thunk()
-    if any(isinstance(a, jax.core.Tracer) for a in args):
+def _obs_conv(pass_: str, thunk, *, attrs):
+    """Run one conv1d pass, logging its resolved config under telemetry
+    (repro.obs, DESIGN.md §14) as a ``conv1d.<pass>.trace`` event, eager
+    or traced alike.  Nothing is timed here: the host cannot time a pass
+    inside a compiled program, and the device trace names each kernel by
+    pass and layer scope.  No jnp ops are added, so enabling telemetry
+    cannot retrace or alter a jaxpr; the disabled path is a single
+    ``enabled()`` check before any dict is built."""
+    if _obs.enabled():
         _obs.event(f"conv1d.{pass_}.trace", **attrs)
-        return thunk()
-
-    def _close(dur: float) -> dict:
-        from repro.obs.provenance import provenance
-        from repro.roofline.analysis import achieved_fraction_of_peak
-        # an unknown device_kind raises here rather than reporting a guess
-        return {"flops": flops,
-                "gflops_per_s": flops / max(dur, 1e-30) / 1e9,
-                "efficiency": achieved_fraction_of_peak(
-                    flops, dur, provenance()["device_kind"])}
-
-    with _obs.span(f"conv1d.{pass_}", close_attrs=_close, **attrs):
-        out = thunk()
-        jax.block_until_ready(out)
-    return out
+    return thunk()
 
 
 class PassConfig(NamedTuple):
@@ -719,9 +695,7 @@ def _conv1d_pallas_bwd(spec, res, gout):
     if spec.model_axes:
         bd_attrs["model_axes"] = ",".join(spec.model_axes)
     # bwd-data contracts over K and produces all W output columns
-    dx = _obs_conv(
-        "bwd_data", bd_thunk, args=(x, du), flops=2.0 * N * C * K * S * W,
-        attrs=dict(bd_attrs, **cell))
+    dx = _obs_conv("bwd_data", bd_thunk, attrs=dict(bd_attrs, **cell))
     dx = dx.astype(x.dtype)
     # --- Alg. 4: bwd-weight kernel (fp32 accumulation), with the bias
     # gradient fused into the same sequential-grid pass when bias exists —
@@ -780,7 +754,7 @@ def _conv1d_pallas_bwd(spec, res, gout):
         else:
             bw_thunk = lambda: bw_range(0, nq)  # noqa: E731
     dwout = _obs_conv(
-        "bwd_weight", bw_thunk, args=(x, du), flops=2.0 * N * C * K * S * Q,
+        "bwd_weight", bw_thunk,
         attrs=dict(bw_attrs, N=N, C=C, K=K, S=S, dilation=d, Q=Q,
                    dtype=jnp.dtype(x.dtype).name, depthwise=False))
     dw, dbias, dres = _epilogue_param_grads(spec, dwout, du, reduced=reduced)
@@ -954,8 +928,7 @@ def conv1d(
         thunk = lambda: _conv1d_pallas(spec, x, w, bias, residual)  # noqa: E731
     else:
         raise ValueError(f"unknown conv backend {backend!r}")
-    return _obs_conv("fwd", thunk, args=(x, w),
-                     flops=2.0 * N * C * K * S * Q, attrs=attrs)
+    return _obs_conv("fwd", thunk, attrs=attrs)
 
 
 # ---------------------------------------------------------------------------
@@ -1172,7 +1145,7 @@ def _dw_conv1d_pallas_bwd(spec, res, gout):
                                       wblk=bd.wblk or spec.wblk, kblk=cblk,
                                       alg=None, nblk=1))
     dx = _obs_conv(
-        "bwd_data", bd_thunk, args=(x, du), flops=2.0 * N * C * S * W,
+        "bwd_data", bd_thunk,
         attrs=dict(bd_attrs, N=N, C=C, K=C, S=S, dilation=d, Q=Q,
                    dtype=jnp.dtype(x.dtype).name, depthwise=True))
     dx = dx.astype(x.dtype)
@@ -1225,7 +1198,7 @@ def _dw_conv1d_pallas_bwd(spec, res, gout):
         else:
             bw_thunk = lambda: bw_range(0, nq)  # noqa: E731
     dwout = _obs_conv(
-        "bwd_weight", bw_thunk, args=(x, du), flops=2.0 * N * C * S * Q,
+        "bwd_weight", bw_thunk,
         attrs=dict(bw_attrs, N=N, C=C, K=C, S=S, dilation=d, Q=Q,
                    dtype=jnp.dtype(x.dtype).name, depthwise=True))
     dw, dbias, dres = _epilogue_param_grads(spec, dwout, du, reduced=reduced)
@@ -1359,5 +1332,4 @@ def depthwise_conv1d(
         thunk = lambda: _dw_conv1d_pallas(spec, x, w, bias, residual)  # noqa: E731
     else:
         raise ValueError(f"unknown conv backend {backend!r}")
-    return _obs_conv("fwd", thunk, args=(x, w),
-                     flops=2.0 * N * C * S * Q, attrs=attrs)
+    return _obs_conv("fwd", thunk, attrs=attrs)

@@ -50,7 +50,6 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import configs, obs
@@ -70,35 +69,6 @@ from repro.train.train_step import init_state, make_phase_probes, \
 # steps excluded from throughput: step 0 pays compile, step 1 still hits
 # first-touch allocator costs — both would poison a samples/s claim
 WARMUP_STEPS = 2
-
-
-def _telemetry_conv_probe(cfg, dilation=None):
-    """Eagerly run the arch's representative conv cell (fwd + vjp pull,
-    backend='auto') once, so a *jitted* training smoke still produces
-    measured per-pass efficiency spans and tuner cache counters — inside
-    the jit those calls are tracers and only log ``.trace`` events."""
-    from repro.kernels import ops
-    C, S = cfg.conv_channels, cfg.conv_filter
-    d = dilation if dilation is not None else cfg.conv_dilation
-    if not (C and S):
-        return
-    x = jnp.ones((1, C, 512), jnp.float32)
-    w = jnp.full((S, C, C), 0.01, jnp.float32)
-
-    def f(w):
-        return ops.conv1d(x, w, dilation=d, padding="SAME", backend="auto")
-
-    ops.conv1d(x, w, dilation=d, padding="SAME", backend="auto")  # timed fwd
-    y, pull = jax.vjp(f, w)
-    pull(jnp.ones_like(y))  # eager custom-VJP pull: timed bwd_* spans
-    # the per-pass custom VJP only exists on the pallas path; where 'auto'
-    # resolves to the library backend (CPU), pin it so bwd_data/bwd_weight
-    # still produce measured spans
-    def fp(w):
-        return ops.conv1d(x, w, dilation=d, padding="SAME", backend="pallas")
-
-    y2, pull2 = jax.vjp(fp, w)
-    pull2(jnp.ones_like(y2))
 
 
 def _parse_args(argv):
@@ -473,14 +443,12 @@ def run(argv=None) -> dict:
                             break
                         if obs.enabled() and i == probe_at:
                             # one-shot measured breakdown (separately jitted
-                            # phase prefixes) + the eager conv probe
+                            # phase prefixes)
                             probes = make_phase_probes(
                                 cfg, mesh=mesh if shard_step else None)
                             for ph, sec in probes(state, batch).items():
                                 obs.span_event(f"train.phase.{ph}", sec,
                                                step=i)
-                            if cfg.family == "conv":
-                                _telemetry_conv_probe(cfg)
                         if (verdict == "restore" and ckpt
                                 and ckpt.latest_step() is not None):
                             print("health: restoring last checkpoint")

@@ -3,7 +3,8 @@
 A zero-dependency, process-local telemetry layer (DESIGN.md §14).  Off by
 default; every hook's disabled path is a single ``is None`` check (no
 allocation, no I/O — sub-microsecond, and **never** part of a jaxpr, so
-toggling telemetry cannot retrace anything).  Enable with
+toggling telemetry cannot retrace anything), except that counters keep
+their totals (``counters()``) with or without a sink.  Enable with
 ``REPRO_TELEMETRY=1`` (sink path from ``REPRO_TELEMETRY_PATH``) or
 explicitly:
 
@@ -23,9 +24,12 @@ explicitly:
     'hi'
 
 What gets instrumented where:
-  * ``kernels/ops.py``     — a span per executed conv1d pass (eager calls:
-    measured wall time + achieved fraction-of-peak vs the roofline); a
-    trace event per *traced* pass recording the resolved config.
+  * ``kernels/ops.py``     — a config event per conv1d pass, eager or
+    traced (``conv1d.<pass>.trace``: backend, tiles, pipelining).
+  * ``kernels/conv1d_brgemm.py`` — a ``kernels.build`` span per kernel
+    build (each ``pallas_call``, at trace time under jit), and the
+    counters ``kernels.build``, ``kernels.build_s`` (seconds in the call)
+    and ``kernels.build_distinct`` (distinct kernel signatures).
   * ``repro.tune``         — cache hit/miss/legacy-upgrade counters and
     per-candidate search traces (predicted vs measured seconds).
   * ``launch/train.py``    — per-step spans (data / step), a measured
@@ -33,16 +37,21 @@ What gets instrumented where:
     step-time gauges, health + straggler rollups.
   * ``train/serve_step.py``— request-level latency spans.
 
+Device time is not measured here: the layer scopes of ``core/blocks.py``
+(``layer.*``) and the step's ``step.*`` scopes name the device ops in a
+profiler trace, and ``obs`` spans appear on that trace's host timeline.
+
 Consumers: ``scripts/obs_report.py`` (scoreboard: p50/p99 per span, conv
-efficiency per cell, tuner hit rate, cost-model error) and
+configs per cell, tuner hit rate, cost-model error) and
 ``python -m repro.obs.trace_export`` (Chrome/Perfetto trace).  See
 docs/observability.md.
 """
 from __future__ import annotations
 
 from .bus import (DEFAULT_PATH, ENV_TELEMETRY, ENV_TELEMETRY_PATH, Span,
-                  counter, counters, disable, enable, enabled, event,
-                  gauge, log_path, span, span_event, _env_enable)
+                  counter, counters, disable, distinct, enable, enabled,
+                  event, gauge, log_path, reset_counters, span, span_event,
+                  _env_enable)
 from .provenance import provenance
 from .schema import read_events, validate
 
@@ -50,7 +59,7 @@ _env_enable()
 
 __all__ = [
     "DEFAULT_PATH", "ENV_TELEMETRY", "ENV_TELEMETRY_PATH", "Span",
-    "counter", "counters", "disable", "enable", "enabled", "event",
-    "gauge", "log_path", "provenance", "read_events", "span",
-    "span_event", "validate",
+    "counter", "counters", "disable", "distinct", "enable", "enabled",
+    "event", "gauge", "log_path", "provenance", "read_events",
+    "reset_counters", "span", "span_event", "validate",
 ]

@@ -3,21 +3,36 @@
 One ``_Bus`` per process (module-level singleton), **off by default**: every
 public hook checks ``_BUS is not None`` first, so a disabled hook is a
 handful of bytecode ops (no dict building, no I/O, no jax interaction —
-tested to stay under a microsecond in tests/test_obs.py).  Nothing here
-imports jax or touches device state: trace-time hooks inside jitted code
-must never change the jaxpr, and enabling telemetry must never retrace.
+tested to stay under a microsecond in tests/test_obs.py).  Counters are
+the exception: their in-memory totals are kept whether or not a sink is
+open (each thread adds into a dict of its own: no lock, still under a
+microsecond), so a caller that never opens a sink — the chip benchmark —
+can read them; a record is written only while a sink is open.  Every
+counter in the program fires at trace time, never inside a compiled step.  Nothing here changes a jaxpr:
+trace-time hooks inside jitted code must never change what is compiled,
+and enabling telemetry must never retrace.
 
-Primitives (all no-ops while disabled):
+Primitives (all but the counter totals no-ops while disabled):
 
   * ``span(name, **attrs)``        — context manager timing a region with a
                                      monotonic clock (``perf_counter``);
                                      spans nest via a thread-local stack and
                                      each record carries its parent id.
+                                     While a sink is open the span also
+                                     opens a ``jax.profiler.TraceAnnotation``
+                                     of the same name, so a profile taken
+                                     then shows it on the host timeline,
+                                     beside the device ops.
   * ``span_event(name, dur, ...)`` — a span whose duration was measured by
-                                     the caller (derived phases).
-  * ``counter(name, value)``       — monotonic increment; the bus keeps
-                                     running totals (``counters()``) and
-                                     logs every increment.
+                                     the caller (derived phases); it is
+                                     written after the fact, so it has no
+                                     profiler annotation.
+  * ``counter(name, value)``       — monotonic increment; ``counters()``
+                                     gives the running totals, and each
+                                     increment is logged while a sink is
+                                     open.
+  * ``distinct(name, key)``        — a counter that counts each ``key`` the
+                                     first time it is seen.
   * ``gauge(name, value)``         — point-in-time sample.
   * ``event(name)``                — zero-duration marker.
 
@@ -33,13 +48,20 @@ import os
 import threading
 import time
 from itertools import count
-from typing import Any, Callable
+from typing import Any
 
 ENV_TELEMETRY = "REPRO_TELEMETRY"
 ENV_TELEMETRY_PATH = "REPRO_TELEMETRY_PATH"
 DEFAULT_PATH = "repro_telemetry.jsonl"
 
 _BUS: "_Bus | None" = None
+# Counter totals and the keys ``distinct`` has seen, kept with or without
+# a sink; ``reset_counters`` clears both.  Each thread adds into a totals
+# dict of its own, so an increment takes no lock; ``counters`` sums them.
+_LOCAL = threading.local()
+_THREAD_TOTALS: list[dict[str, float]] = []
+_SEEN: dict[str, set] = {}
+_COUNT_LOCK = threading.Lock()
 
 
 class _Bus:
@@ -53,7 +75,6 @@ class _Bus:
         self._local = threading.local()
         self.epoch = time.perf_counter()
         self.wall_epoch = time.time()
-        self.totals: dict[str, float] = {}
         from .provenance import provenance
         self.pid = int(provenance().get("process_index", 0))
         self.emit({"kind": "meta", "name": "provenance", "ts": 0.0,
@@ -84,19 +105,16 @@ class _Bus:
 
 class Span:
     """One timed region.  Emitted at ``__exit__``; ``attrs`` may be mutated
-    inside the ``with`` block, and ``close_attrs(dur_seconds)`` — if given —
-    supplies duration-derived attrs (e.g. achieved fraction of peak) at
-    close time.  ``dur`` is readable after the block."""
+    inside the ``with`` block.  ``dur`` is readable after the block."""
 
-    __slots__ = ("name", "attrs", "close_attrs", "id", "parent", "_t0",
-                 "ts", "dur")
+    __slots__ = ("name", "attrs", "id", "parent", "_t0", "ts", "dur",
+                 "_annotation")
 
-    def __init__(self, name: str, attrs: dict,
-                 close_attrs: Callable[[float], dict] | None = None):
+    def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
-        self.close_attrs = close_attrs
         self.dur = None
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         bus = _BUS
@@ -104,6 +122,10 @@ class Span:
             self.id = self.parent = None
             self._t0 = time.perf_counter()
             return self
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         st = bus.stack()
         self.id = next(bus._ids)
         self.parent = st[-1] if st else None
@@ -114,14 +136,15 @@ class Span:
 
     def __exit__(self, *exc) -> None:
         self.dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         bus = _BUS
         if bus is None or self.id is None:
             return
         st = bus.stack()
         if st and st[-1] == self.id:
             st.pop()
-        if self.close_attrs is not None:
-            self.attrs.update(self.close_attrs(self.dur))
         bus.emit({"kind": "span", "name": self.name, "ts": self.ts,
                   "dur": self.dur, "id": self.id, "parent": self.parent,
                   "attrs": self.attrs})
@@ -180,12 +203,12 @@ def log_path() -> str | None:
     return _BUS.path if _BUS is not None else None
 
 
-def span(name: str, close_attrs: Callable[[float], dict] | None = None,
-         **attrs):
-    """Context manager timing a region; nests via a thread-local stack."""
+def span(name: str, **attrs):
+    """Context manager timing a region; nests via a thread-local stack.
+    While a sink is open it is also a ``jax.profiler.TraceAnnotation``."""
     if _BUS is None:
         return _NOOP_SPAN
-    return Span(name, attrs, close_attrs)
+    return Span(name, attrs)
 
 
 def span_event(name: str, dur: float, **attrs) -> None:
@@ -201,12 +224,40 @@ def span_event(name: str, dur: float, **attrs) -> None:
 
 
 def counter(name: str, value: float = 1, **attrs) -> None:
+    """Add ``value`` to the total of ``name``; logged while a sink is
+    open, totalled always."""
+    try:
+        mine = _LOCAL.totals
+    except AttributeError:
+        mine = _own_totals()
+    mine[name] = mine.get(name, 0) + value
     bus = _BUS
     if bus is None:
         return
-    bus.totals[name] = bus.totals.get(name, 0) + value
     bus.emit({"kind": "counter", "name": name, "ts": bus.now(),
-              "value": value, "total": bus.totals[name], "attrs": attrs})
+              "value": value, "total": counters()[name], "attrs": attrs})
+
+
+def _own_totals() -> dict[str, float]:
+    """This thread's counter totals, made and registered on first use."""
+    mine = _LOCAL.totals = {}
+    with _COUNT_LOCK:
+        _THREAD_TOTALS.append(mine)
+    return mine
+
+
+def distinct(name: str, key) -> None:
+    """Count ``key`` (hashable) under counter ``name`` the first time it
+    is seen."""
+    seen = _SEEN.get(name)
+    if seen is not None and key in seen:  # seen keys stay seen: no lock
+        return
+    with _COUNT_LOCK:
+        seen = _SEEN.setdefault(name, set())
+        new = key not in seen
+        seen.add(key)
+    if new:
+        counter(name)
 
 
 def gauge(name: str, value: float, **attrs) -> None:
@@ -225,8 +276,23 @@ def event(name: str, **attrs) -> None:
 
 
 def counters() -> dict[str, float]:
-    """Snapshot of the in-process counter totals ({} while disabled)."""
-    return dict(_BUS.totals) if _BUS is not None else {}
+    """Snapshot of the in-process counter totals, sink or no sink: every
+    thread's totals summed."""
+    out: dict[str, float] = {}
+    with _COUNT_LOCK:
+        for totals in _THREAD_TOTALS:
+            for name, value in dict(totals).items():  # an atomic copy
+                out[name] = out.get(name, 0) + value
+    return out
+
+
+def reset_counters() -> None:
+    """Zero every counter total and forget every ``distinct`` key.  An
+    increment another thread has in flight may survive the reset."""
+    with _COUNT_LOCK:
+        for totals in _THREAD_TOTALS:
+            totals.clear()
+        _SEEN.clear()
 
 
 def _env_enable() -> None:

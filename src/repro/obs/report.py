@@ -1,18 +1,19 @@
-"""Aggregate a telemetry JSONL log into an efficiency scoreboard.
+"""Aggregate a telemetry JSONL log into a scoreboard.
 
-The paper argues in *achieved fraction of roofline peak* (80% on Cascade
-Lake) — so does this report: conv1d pass spans carry measured efficiency,
-tuner counters give the cache hit rate, candidate search traces give the
-cost-model error distribution, and train-step spans give the end-to-end
-breakdown.  ``scripts/obs_report.py`` is the CLI; tests import
-``aggregate`` directly.
+Conv1d config events give the resolved configuration of each conv cell and
+pass, tuner counters give the cache hit rate, candidate search traces give
+the cost-model error distribution, and train-step spans give the
+end-to-end breakdown.  Device time is not in the log: a profiler trace
+gives it, by the program's layer scopes.  ``scripts/obs_report.py`` is the
+CLI; tests import ``aggregate`` directly.
 
 Sections (keys of ``aggregate``'s result):
   provenance  the log's identity block
   spans       per-name count / p50 / p99 / total seconds
-  conv_cells  per (cell, pass): count, p50 ms, median efficiency, plus
-              the pipelining axis (max pipe depth dispatched, median
-              model-derived overlap fraction — DESIGN.md §15)
+  conv_cells  per (cell, pass): count of ``conv1d.<pass>.trace`` config
+              events, plus the pipelining axis (max pipe depth
+              dispatched, median model-derived overlap fraction —
+              DESIGN.md §15)
   tuner       cache hits / misses / legacy upgrades / hit rate
   cost_model  predicted-vs-measured ratio distribution over search traces
   steps       train.step count + latency percentiles + phase breakdown
@@ -70,7 +71,7 @@ def aggregate(events: Iterable[dict[str, Any]]) -> dict[str, Any]:
                       {})
     spans: dict[str, list[float]] = defaultdict(list)
     cells: dict[tuple[str, str], dict[str, list[float]]] = defaultdict(
-        lambda: {"dur": [], "eff": [], "gflops": [], "pipe": [], "ovl": []})
+        lambda: {"count": 0, "pipe": [], "ovl": []})
     counters: dict[str, float] = defaultdict(float)
     searches: list[dict] = []
     phase_durs: dict[str, list[float]] = defaultdict(list)
@@ -101,16 +102,6 @@ def aggregate(events: Iterable[dict[str, Any]]) -> dict[str, Any]:
                     "dp_to": attrs.get("dp_to"), "mp": attrs.get("mp"),
                     "time_to_restore_s": r["dur"],
                     "ts": float(r.get("ts", 0.0)) + r["dur"]})
-            if name.startswith("conv1d."):
-                c = cells[(_conv_cell_key(attrs), name[len("conv1d."):])]
-                c["dur"].append(r["dur"])
-                if "efficiency" in attrs:
-                    c["eff"].append(attrs["efficiency"])
-                if "gflops_per_s" in attrs:
-                    c["gflops"].append(attrs["gflops_per_s"])
-                if "pipe_depth" in attrs:  # pipelining axis (DESIGN.md §15)
-                    c["pipe"].append(int(attrs["pipe_depth"]))
-                    c["ovl"].append(float(attrs.get("overlap_frac", 0.0)))
             if name.startswith("train.phase."):
                 phase_durs[name[len("train.phase."):]].append(r["dur"])
             if name.startswith("serve.conv."):
@@ -137,11 +128,12 @@ def aggregate(events: Iterable[dict[str, Any]]) -> dict[str, Any]:
             m["bytes"] += int(attrs.get("bytes", 0))
         elif (kind == "event" and name.startswith("conv1d.")
                 and name.endswith(".trace")):
-            # jitted dispatches emit zero-duration trace events instead of
-            # timed spans — still the record of which pipeline depth ran
+            # one config record per pass, eager or traced: which backend,
+            # tiles and pipeline depth ran (DESIGN.md §15)
+            c = cells[(_conv_cell_key(attrs),
+                       name[len("conv1d."):-len(".trace")])]
+            c["count"] += 1
             if "pipe_depth" in attrs:
-                c = cells[(_conv_cell_key(attrs),
-                           name[len("conv1d."):-len(".trace")])]
                 c["pipe"].append(int(attrs["pipe_depth"]))
                 c["ovl"].append(float(attrs.get("overlap_frac", 0.0)))
 
@@ -218,9 +210,7 @@ def aggregate(events: Iterable[dict[str, Any]]) -> dict[str, Any]:
         "spans": {n: _span_stats(d) for n, d in sorted(spans.items())},
         "conv_cells": {
             f"{cell}|{pass_}": {
-                "count": len(c["dur"]), "p50_ms": _pct(c["dur"], 0.5) * 1e3,
-                "efficiency_p50": _pct(c["eff"], 0.5),
-                "gflops_per_s_p50": _pct(c["gflops"], 0.5),
+                "count": c["count"],
                 "pipe_depth_max": max(c["pipe"], default=0),
                 # overlap over pipelined dispatches only — mixing in the
                 # synchronous spans' zeros would hide a broken estimate
@@ -267,15 +257,12 @@ def render_text(agg: dict[str, Any]) -> str:
                    f"{_fmt(s['p50_s'] * 1e3, 'ms'):>10s} "
                    f"{_fmt(s['p99_s'] * 1e3, 'ms'):>10s} "
                    f"{_fmt(s['total_s'], 's'):>9s}")
-    out += ["", "-- conv1d efficiency (achieved fraction of roofline peak)"]
+    out += ["", "-- conv1d passes (config records per cell)"]
     for cell, c in agg["conv_cells"].items():
         pipe = (f" pipe={c['pipe_depth_max']} "
                 f"ovl={_fmt(c['overlap_frac_p50'])}"
                 if c.get("pipe_depth_max", 0) >= 2 else "")
-        out.append(f"  {cell:54s} n={c['count']:<4d} "
-                   f"{_fmt(c['p50_ms'], 'ms'):>9s} "
-                   f"eff={_fmt(c['efficiency_p50'])} "
-                   f"({_fmt(c['gflops_per_s_p50'])} GFLOP/s){pipe}")
+        out.append(f"  {cell:54s} n={c['count']:<4d}{pipe}")
     t = agg["tuner"]
     out += ["", f"-- tuner cache: hits {t['hits']} misses {t['misses']} "
                 f"legacy-upgrades {t['legacy_upgrades']} "
@@ -342,9 +329,8 @@ def check(agg: dict[str, Any]) -> list[str]:
     """The CI smoke gate: names of the required sections that are missing
     from an instrumented training run's log (empty list = pass)."""
     missing = []
-    if not any(c["count"] and c["efficiency_p50"] == c["efficiency_p50"]
-               for c in agg["conv_cells"].values()):
-        missing.append("conv_cells (no measured conv1d pass efficiency)")
+    if not any(c["count"] for c in agg["conv_cells"].values()):
+        missing.append("conv_cells (no conv1d pass config records)")
     if not agg["steps"]["count"]:
         missing.append("steps (no train.step spans)")
     if not agg["steps"].get("phases"):
@@ -441,7 +427,7 @@ def check_pipelining(agg: dict[str, Any]) -> list[str]:
     model-derived overlap fraction."""
     if not any(c.get("pipe_depth_max", 0) >= 2
                for c in agg["conv_cells"].values()):
-        return ["pipelining (no pipelined conv1d pass spans in the log)"]
+        return ["pipelining (no pipelined conv1d pass records in the log)"]
     return _zero_overlap_cells(agg)
 
 
@@ -453,8 +439,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("log", help="telemetry JSONL path")
     ap.add_argument("--json", action="store_true", help="emit JSON")
     ap.add_argument("--check", action="store_true",
-                    help="exit 1 unless conv efficiency, step breakdown "
-                         "and tuner sections are all present (CI gate)")
+                    help="exit 1 unless conv config records, step "
+                         "breakdown and tuner sections are all present "
+                         "(CI gate)")
     ap.add_argument("--check-pipelining", action="store_true",
                     help="exit 1 unless pipelined conv passes ran and "
                          "every pipelined cell reports a nonzero overlap "

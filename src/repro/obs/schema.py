@@ -2,7 +2,7 @@
 
 Common fields (every record):
   kind   'meta' | 'span' | 'counter' | 'gauge' | 'event'
-  name   dotted event name ('conv1d.fwd', 'tune.cache.hit', 'train.step')
+  name   dotted event name ('kernels.build', 'tune.cache.hit', 'train.step')
   ts     seconds since the log's monotonic epoch (float, >= 0)
   attrs  flat JSON object of event attributes
   pid    jax process index of the emitting process

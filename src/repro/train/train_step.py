@@ -202,12 +202,15 @@ def make_train_step(cfg, *, accum_steps: int = 1, peak_lr: float = 3e-4,
             grads, state.opt, state.params, lr=lr,
             weight_decay=weight_decay, grad_clip=grad_clip)
         if skip_nonfinite:
-            finite = jnp.isfinite(metrics["grad_norm"]) & jnp.isfinite(loss)
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(finite, n, o), new_params, state.params)
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(finite, n, o), new_opt, state.opt)
-            metrics["skipped"] = (~finite).astype(jnp.float32)
+            with jax.named_scope("step.guard"):
+                finite = (jnp.isfinite(metrics["grad_norm"])
+                          & jnp.isfinite(loss))
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(finite, n, o), new_params,
+                    state.params)
+                new_opt = jax.tree.map(
+                    lambda n, o: jnp.where(finite, n, o), new_opt, state.opt)
+                metrics["skipped"] = (~finite).astype(jnp.float32)
         new_state = TrainState(new_params, new_opt, state.step + 1, new_ef)
         metrics.update(loss=loss, lr=lr)
         return new_state, metrics

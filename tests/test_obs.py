@@ -1,9 +1,14 @@
 """Telemetry subsystem (repro.obs): schema contract, disabled-path no-op
-(no events, no retraces, sub-microsecond hooks), span nesting, kernel/tuner
+(no events, no retraces, sub-microsecond hooks), span nesting and its
+profiler annotation, counters kept without a sink, kernel/tuner
 instrumentation accuracy, straggler detection from gauges, and the two
 consumers (scoreboard + Chrome-trace export)."""
 import json
+import os
+import sys
+import threading
 import timeit
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -18,11 +23,13 @@ from repro.runtime.straggler import ShardStragglerMonitor
 
 @pytest.fixture(autouse=True)
 def _telemetry_off():
-    """Every test starts and ends with telemetry disabled (the bus is a
-    process-global singleton)."""
+    """Every test starts and ends with telemetry disabled and the counter
+    totals at zero (the bus and the totals are process-global)."""
     obs.disable()
+    obs.reset_counters()
     yield
     obs.disable()
+    obs.reset_counters()
 
 
 def _log(tmp_path, name="t.jsonl"):
@@ -79,13 +86,18 @@ class TestDisabledPath:
             obs.event("e")
             obs.span_event("se", 0.1)
         assert s.dur is None  # the shared no-op span
-        assert obs.counters() == {}
+        # counters keep their totals without a sink, and write nothing
+        assert obs.counters() == {"c": 1}
         assert obs.log_path() is None
         assert list(tmp_path.iterdir()) == []
 
     def test_disabled_hooks_under_one_microsecond(self):
         n = 20_000
+        obs.distinct("d", "seen")
+        # a counter's disabled path updates its in-memory total; every
+        # other hook returns at once
         for hook in (lambda: obs.counter("c"),
+                     lambda: obs.distinct("d", "seen"),
                      lambda: obs.gauge("g", 1.0),
                      lambda: obs.span("s")):
             # min-of-repeats: scheduler noise only ever inflates a sample
@@ -116,6 +128,52 @@ class TestDisabledPath:
         obs.enable(_log(tmp_path))
         on = str(jax.make_jaxpr(f)(x))
         assert on == off
+
+
+class TestCounters:
+    def test_totals_kept_with_and_without_a_sink(self, tmp_path):
+        obs.counter("k", 2)
+        path = obs.enable(_log(tmp_path))
+        obs.counter("k", 3)
+        obs.disable()
+        obs.counter("k")
+        assert obs.counters()["k"] == 6
+        [rec] = [r for r in obs.read_events(path) if r["kind"] == "counter"]
+        assert rec["value"] == 3 and rec["total"] == 5
+        obs.reset_counters()
+        assert obs.counters() == {}
+
+    def test_distinct_counts_each_key_once(self):
+        for key in ("a", "b", "a", ("c", 1), ("c", 1), "b"):
+            obs.distinct("seen", key)
+        assert obs.counters()["seen"] == 3
+        obs.reset_counters()
+        obs.distinct("seen", "a")
+        assert obs.counters()["seen"] == 1
+
+    def test_concurrent_increments_are_not_lost(self):
+        """More threads than cores, switching often: every increment and
+        every first sighting of a key is counted exactly once."""
+        n_threads, n_incr = 4 * (os.cpu_count() or 1), 2_000
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            def work(t):
+                for i in range(n_incr):
+                    obs.counter("stress")
+                    obs.distinct("stress.keys", i % 50)
+
+            threads = [threading.Thread(target=work, args=(t,))
+                       for t in range(n_threads)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+            assert not any(th.is_alive() for th in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        assert obs.counters()["stress"] == n_threads * n_incr
+        assert obs.counters()["stress.keys"] == 50
 
 
 class TestSpans:
@@ -149,13 +207,14 @@ class TestSpans:
         assert spans["derived"]["parent"] == spans["outer"]["id"]
         assert spans["derived"]["dur"] == 0.25
 
-    def test_close_attrs_sees_duration(self, tmp_path):
+    def test_attrs_set_inside_the_block_are_recorded(self, tmp_path):
         path = obs.enable(_log(tmp_path))
-        with obs.span("s", close_attrs=lambda dur: {"twice": 2 * dur}):
-            pass
+        with obs.span("s", given=1) as sp:
+            sp.attrs["found"] = 2
         obs.disable()
         [rec] = [r for r in obs.read_events(path) if r["kind"] == "span"]
-        assert rec["attrs"]["twice"] == pytest.approx(2 * rec["dur"])
+        assert rec["attrs"] == {"given": 1, "found": 2}
+        assert rec["dur"] == sp.dur > 0
 
     def test_reenable_same_path_appends(self, tmp_path):
         path = obs.enable(_log(tmp_path))
@@ -166,9 +225,32 @@ class TestSpans:
         names = [r["name"] for r in obs.read_events(path)]
         assert names == ["provenance", "one", "two"]
 
+    def test_span_appears_in_profiler_trace(self, tmp_path):
+        """While a sink is open, a span is also a profiler annotation: it
+        shows on the host plane of a ``jax.profiler`` trace."""
+        from jax.profiler import ProfileData
+
+        obs.enable(_log(tmp_path))
+        trace_dir = tmp_path / "trace"
+        jax.profiler.start_trace(str(trace_dir))
+        with obs.span("demo.annotated"):
+            jnp.ones(8).block_until_ready()
+        obs.span_event("demo.after_the_fact", 0.1)
+        jax.profiler.stop_trace()
+        obs.disable()
+        [pb] = Path(trace_dir).glob("plugins/profile/*/*.xplane.pb")
+        host = {ev.name
+                for plane in ProfileData.from_file(str(pb)).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events}
+        assert "demo.annotated" in host
+        assert "demo.after_the_fact" not in host
+
 
 class TestKernelInstrumentation:
     def test_eager_conv_passes_get_measured_spans(self, tmp_path):
+        """Eager passes are not timed: each logs its config event, and the
+        only spans are the kernel builds."""
         path = obs.enable(_log(tmp_path))
         x = jnp.ones((2, 8, 64))
         w = jnp.ones((3, 4, 8))
@@ -177,18 +259,25 @@ class TestKernelInstrumentation:
             lambda w: ops.conv1d(x, w, dilation=2, backend="pallas"), w)
         pull(jnp.ones_like(y))
         obs.disable()
-        spans = {r["name"]: r for r in obs.read_events(path)
-                 if r["kind"] == "span"}
-        assert {"conv1d.fwd", "conv1d.bwd_data",
-                "conv1d.bwd_weight"} <= set(spans)
-        fwd = spans["conv1d.fwd"]["attrs"]
+        recs = obs.read_events(path)
+        events = {r["name"]: r for r in recs if r["kind"] == "event"}
+        assert {"conv1d.fwd.trace", "conv1d.bwd_data.trace",
+                "conv1d.bwd_weight.trace"} <= set(events)
+        fwd = events["conv1d.fwd.trace"]["attrs"]
         assert fwd["backend"] == "pallas" and not fwd["depthwise"]
         assert (fwd["N"], fwd["C"], fwd["K"], fwd["S"]) == (2, 8, 4, 3)
-        # measured wall time -> roofline attribution on every pass
-        for name in ("conv1d.fwd", "conv1d.bwd_data", "conv1d.bwd_weight"):
-            a = spans[name]["attrs"]
-            assert a["gflops_per_s"] > 0
-            assert 0 < a["efficiency"] < 1
+        for r in recs:
+            assert "efficiency" not in r["attrs"]
+            assert "gflops_per_s" not in r["attrs"]
+        spans = [r for r in recs if r["kind"] == "span"]
+        assert {r["name"] for r in spans} == {"kernels.build"}
+        # fwd twice (plain and under vjp), bwd_data, bwd_weight
+        assert [r["attrs"]["kernel"] for r in spans] == [
+            "conv1d_fwd", "conv1d_fwd", "conv1d_bwd_data",
+            "conv1d_bwd_weight"]
+        assert spans[0]["attrs"]["dtype"] == "float32"
+        assert obs.counters()["kernels.build"] == 4
+        assert obs.counters()["kernels.build_distinct"] == 3
 
     def test_traced_conv_logs_trace_event_only(self, tmp_path):
         path = obs.enable(_log(tmp_path))
@@ -199,9 +288,13 @@ class TestKernelInstrumentation:
         recs = obs.read_events(path)
         assert [r["name"] for r in recs if r["kind"] == "event"] \
             == ["conv1d.fwd.trace"]
-        assert not [r for r in recs if r["kind"] == "span"]
+        # the one span is the kernel's build at trace time; no pass is timed
+        assert [r["name"] for r in recs if r["kind"] == "span"] \
+            == ["kernels.build"]
 
     def test_depthwise_spans(self, tmp_path):
+        """Depthwise passes log config events marked depthwise; their
+        kernel builds are the only spans."""
         path = obs.enable(_log(tmp_path))
         x = jnp.ones((2, 16, 64))
         w = jnp.ones((4, 16))
@@ -209,10 +302,16 @@ class TestKernelInstrumentation:
             lambda w: ops.depthwise_conv1d(x, w, backend="pallas"), w)
         pull(jnp.ones_like(y))
         obs.disable()
-        spans = [r for r in obs.read_events(path) if r["kind"] == "span"]
-        assert {s["name"] for s in spans} == {"conv1d.bwd_data",
-                                              "conv1d.bwd_weight"}
-        assert all(s["attrs"]["depthwise"] for s in spans)
+        recs = obs.read_events(path)
+        events = [r for r in recs if r["name"].startswith("conv1d.")]
+        assert {e["name"] for e in events} == {
+            "conv1d.fwd.trace", "conv1d.bwd_data.trace",
+            "conv1d.bwd_weight.trace"}
+        assert all(e["kind"] == "event" and e["attrs"]["depthwise"]
+                   for e in events)
+        spans = [r for r in recs if r["kind"] == "span"]
+        assert {s["attrs"]["kernel"] for s in spans} == {
+            "dwconv1d_fwd", "dwconv1d_bwd_data", "dwconv1d_bwd_weight"}
 
 
 class TestTunerInstrumentation:
@@ -331,7 +430,11 @@ class TestConsumers:
         assert agg["steps"]["phases"]["forward"]["p50_s"] == 0.005
         assert agg["tuner"]["hits"] == 1
         [cell] = [k for k in agg["conv_cells"] if k.endswith("|bwd_weight")]
-        assert agg["conv_cells"][cell]["efficiency_p50"] > 0
+        assert agg["conv_cells"][cell]["count"] == 1
+        for c in agg["conv_cells"].values():
+            assert not {"efficiency_p50", "gflops_per_s_p50",
+                        "p50_ms"} & set(c)
+        assert agg["counters"]["kernels.build"] == 3
         text = report.render_text(agg)
         assert "train.step" in text and "tuner cache" in text
 
@@ -360,7 +463,7 @@ class TestConsumers:
         assert len(evs) == n > 0
         assert trace["metadata"]["provenance"]["jax_version"]
         complete = [e for e in evs if e["ph"] == "X"]
-        assert {"conv1d.bwd_data", "train.step"} <= \
+        assert {"kernels.build", "train.step"} <= \
             {e["name"] for e in complete}
         for e in complete:
             assert e["dur"] >= 0 and e["ts"] >= 0
