@@ -54,8 +54,11 @@ axis advances ``nblk`` samples per cell and their width tiles are
 concatenated into the GEMM width dimension, so small-N, small-Q problems
 still present a wide (nblk·WBLK) operand to the MXU and amortise the tap
 block staging over nblk samples.  ``repro.tune`` searches both axes per
-pass; the defaults (``tap_loop``, ``nblk=1``) reproduce the historical
-kernel exactly.
+pass; without a tuner, ``kernels/ops.py`` (``pick_alg``) packs a pass
+whose packed GEMM dimension is under one MXU tile, as every AtacWorks
+pass but the heads' weight gradient is, and keeps the tap loop
+otherwise.  Called directly, the kernels default to ``tap_loop``,
+``nblk=1``.
 
 Staging has two schedules, selected by ``pipe`` (DESIGN.md §15):
 
@@ -282,9 +285,10 @@ def conv1d_pass(pass_: str, *args, depthwise: bool = False, **kw):
     Each call is one kernel build: under jit it traces one ``pallas_call``
     (Mosaic lowers it later, with the step).  It runs in a
     ``kernels.build`` span and adds to the ``repro.obs`` counters
-    ``kernels.build``, ``kernels.build_s`` (seconds in the call) and
-    ``kernels.build_distinct`` (distinct signatures), all at trace time,
-    never inside a compiled step.
+    ``kernels.build``, ``kernels.build_packed`` (the builds that contract
+    in the ``tap_packed`` formulation), ``kernels.build_s`` (seconds in
+    the call) and ``kernels.build_distinct`` (distinct signatures), all at
+    trace time, never inside a compiled step.
     """
     prefix = "dwconv1d" if depthwise else "conv1d"
     if pass_ == "bwd_weight":
@@ -299,6 +303,8 @@ def conv1d_pass(pass_: str, *args, depthwise: bool = False, **kw):
     with _obs.span("kernels.build", **attrs):
         out = fn(*args, name=name, **kw)
     _obs.counter("kernels.build")
+    if kw.get("alg") == "tap_packed":
+        _obs.counter("kernels.build_packed")
     _obs.counter("kernels.build_s", time.perf_counter() - t0)
     _obs.distinct("kernels.build_distinct", _build_signature(name, args, kw))
     return out

@@ -116,8 +116,9 @@ class PassConfig(NamedTuple):
     (tiles K for the forward, C for bwd-data's transposed GEMM, unused for
     bwd-weight), cblk on the depthwise path.  ``alg`` selects the dense
     contraction formulation (tap_loop / tap_packed, DESIGN.md §12) and
-    ``nblk`` the batch fold; both default to the historical kernel (None ->
-    tap_loop / 1) so legacy 3-tuples keep converting."""
+    ``nblk`` the batch fold; None leaves the formulation to the pass's
+    shape (``pick_alg``) and the fold at 1, so legacy 3-tuples keep
+    converting."""
     backend: str = "pallas"      # 'pallas' | 'xla'
     wblk: int | None = None
     blk2: int | None = None
@@ -159,13 +160,19 @@ def _resolve_auto(x, *, C, K, S, dilation, padding, wblk, kblk, depthwise,
     Q = x.shape[-1] - (S - 1) * dilation
     kw = dict(N=N, C=C, K=K, S=S, dilation=dilation, Q=Q, dtype=x.dtype,
               padding=padding, depthwise=depthwise, epilogue=epilogue)
+
+    def alg(cfg):
+        # a cache entry without one was measured on the tap loop; the
+        # heuristic default (a miss) leaves it to ``pick_alg``
+        return cfg.alg or ("tap_loop" if cfg.source == "cache" else None)
+
     fwd = tune.get_config(**kw)
     bwd = []
     for p in ("bwd_data", "bwd_weight"):
         cfg = tune.get_config(**kw, pass_=p, allow_measure=False)
-        bwd.append(PassConfig(cfg.backend, cfg.wblk, cfg.kblk, cfg.alg,
+        bwd.append(PassConfig(cfg.backend, cfg.wblk, cfg.kblk, alg(cfg),
                               cfg.nblk, cfg.pipe))
-    return (fwd.backend, wblk or fwd.wblk, kblk or fwd.kblk, fwd.alg,
+    return (fwd.backend, wblk or fwd.wblk, kblk or fwd.kblk, alg(fwd),
             fwd.nblk, fwd.pipe, tuple(bwd))
 
 
@@ -212,6 +219,51 @@ def pick_kblk(n_filters: int) -> int:
     return n_filters
 
 
+def pick_alg(pass_: str, *, N: int, C: int, K: int, S: int, dilation: int,
+             Q: int, dtypes, wblk: int, kblk: int | None = None,
+             nblk: int = 1, pipe: int = 0, epilogue: str = "none") -> str:
+    """Dense contraction formulation (DESIGN.md §12) of one pass that
+    neither the caller nor the tuner pinned, read from the pass's own GEMM.
+
+    ``tap_packed`` when the pass has taps to pack (S > 1), the GEMM
+    dimension packing widens is narrower than one MXU tile once
+    ``_kernel_pass`` has padded it to the sublane tile (the input
+    channels C of the forward and of bwd-weight's per-tap output columns,
+    bwd-data's contraction K), and the packed working set fits the
+    tuner's VMEM budget (``tune.space``); ``tap_loop`` otherwise.  Each
+    of the S taps of the loop fills only that dimension's share of the
+    MXU's 128 rows; packing contracts all S at once.
+
+    bwd-weight also needs at least as many streamed rows (K) as per-tap
+    output columns (C): its packed GEMM's work on the S·C packed rows
+    each width tile (split into bf16 parts for ``HIGHEST``, transposed
+    to face the cotangent) grows with C, while the tap loop's MXU time
+    grows with K.  On a v5e (PERF.md §6) the AtacWorks heads'
+    bwd-weight (K=1 padded to 8, C=15 to 16) ran 13.8 ms looped and
+    14.3 ms packed; K=C=16 ran 25.9 and 14.4.
+
+    N, C, K and Q are the forward layer's numbers; ``dtypes`` are those
+    ``_kernel_pass`` pads for (None entries are skipped)."""
+    if S == 1:
+        return "tap_loop"
+    from repro.tune import cost, problem, space  # late: tune imports ops
+
+    dtypes = [jnp.dtype(d) for d in dtypes if d is not None]
+    sub = max(_k.sublane_tile(d) for d in dtypes)
+    prob = problem.ConvProblem(
+        N=N, C=_round_up(C, sub), K=_round_up(K, sub), S=S,
+        dilation=dilation, Q=Q, dtype=max(dtypes, key=lambda d: d.itemsize),
+        epilogue=epilogue, pass_=pass_)
+    if prob.contraction >= cost.MXU_DIM:
+        return "tap_loop"
+    if pass_ == "bwd_weight" and prob.K < prob.C:
+        return "tap_loop"
+    kblk = _sublane_tile_of(kblk, prob.blk2_dim, sub)  # None: untiled
+    fits = space.vmem_footprint_bytes(prob, wblk, kblk, "tap_packed", nblk,
+                                      pipe) <= space.VMEM_BUDGET_BYTES
+    return "tap_packed" if fits else "tap_loop"
+
+
 def _legal_nblk(nblk: int | None, N: int) -> int:
     """A batch fold is usable only when it divides the batch; anything else
     (including a tuned nblk applied to a different batch at trace time)
@@ -226,6 +278,11 @@ def _pad_axis(a, axis: int, size: int):
     pads = [(0, 0)] * a.ndim
     pads[axis] = (0, size - a.shape[axis])
     return jnp.pad(a, pads)
+
+
+def _sublane_tile_of(blk, n, sub: int):
+    """``blk`` where it tiles ``n`` in whole sublane tiles, else None."""
+    return blk if blk and n and n % blk == 0 and blk % sub == 0 else None
 
 
 def _kernel_pass(pass_: str, x, b, *, depthwise: bool = False, bias=None,
@@ -248,12 +305,9 @@ def _kernel_pass(pass_: str, x, b, *, depthwise: bool = False, bias=None,
     C = x.shape[1]
     Cp = _round_up(C, sub)
     x = _pad_axis(x, 1, Cp)
-
-    def tile(blk, n):
-        return blk if blk and n % blk == 0 and blk % sub == 0 else None
-
     if depthwise:
-        kw["cblk"] = tile(cblk, Cp) or _k.default_cblk(Cp, align=sub)
+        kw["cblk"] = (_sublane_tile_of(cblk, Cp, sub)
+                      or _k.default_cblk(Cp, align=sub))
     if pass_ == "bwd_weight":
         n = b.shape[1]
         out = _k.conv1d_pass(pass_, x, _pad_axis(b, 1, _round_up(n, sub)),
@@ -267,7 +321,7 @@ def _kernel_pass(pass_: str, x, b, *, depthwise: bool = False, bias=None,
         n = b.shape[1]
         n_pad = _round_up(n, sub)
         w = _pad_axis(_pad_axis(b, 2, Cp), 1, n_pad)
-        kw["kblk"] = tile(kblk, n_pad) or n_pad
+        kw["kblk"] = _sublane_tile_of(kblk, n_pad, sub) or n_pad
     out = _k.conv1d_pass(pass_, x, w, depthwise=depthwise,
                          bias=_pad_axis(bias, 0, n_pad),
                          residual=_pad_axis(residual, 1, n_pad),
@@ -655,18 +709,22 @@ def _conv1d_pallas_bwd(spec, res, gout):
         kblk = bd.blk2 if bd.blk2 and C % bd.blk2 == 0 else pick_kblk(C)
         bd_pipe = _k.canon_pipe(bd.pipe)
         bd_wblk = bd.wblk or spec.wblk
+        bd_alg = bd.alg or pick_alg(
+            "bwd_data", N=N, C=C, K=K, S=S, dilation=d, Q=Q,
+            dtypes=(du.dtype, w.dtype), wblk=bd_wblk, kblk=kblk,
+            nblk=_legal_nblk(bd.nblk, N), pipe=bd_pipe)
         bd_run = lambda: _plain_fwd_padded(  # noqa: E731
             g_pad, w_flip, d, bd_wblk, kblk,
             spec.interpret, pass_="bwd_data",
-            alg=bd.alg or "tap_loop", nblk=bd.nblk or 1, pipe=bd_pipe)
+            alg=bd_alg, nblk=bd.nblk or 1, pipe=bd_pipe)
         bd_attrs = dict(backend="pallas", wblk=bd_wblk,
-                        kblk=kblk, alg=bd.alg or "tap_loop",
+                        kblk=kblk, alg=bd_alg,
                         nblk=bd.nblk or 1,
                         **_pipe_attrs(bd_pipe, pass_="bwd_data", N=N, C=C,
                                       K=K, S=S, dilation=d, Q=Q,
                                       dtype=x.dtype, depthwise=False,
                                       wblk=bd_wblk, kblk=kblk,
-                                      alg=bd.alg or "tap_loop",
+                                      alg=bd_alg,
                                       nblk=bd.nblk or 1))
         Wp = _round_up(W, bd_wblk)
         nw = Wp // bd_wblk
@@ -683,8 +741,7 @@ def _conv1d_pallas_bwd(spec, res, gout):
                 lambda a, b: _plain_fwd_padded(
                     gp2[:, :, a * bd_wblk:b * bd_wblk + span], w_flip, d,
                     bd_wblk, kblk, spec.interpret, pass_="bwd_data",
-                    alg=bd.alg or "tap_loop", nblk=bd.nblk or 1,
-                    pipe=bd_pipe),
+                    alg=bd_alg, nblk=bd.nblk or 1, pipe=bd_pipe),
                 ranges, spec.model_axes, cell=cell)[:, :, :W]
             bd_attrs["model_chunks"] = len(ranges)
         elif spec.model_axes:
@@ -721,8 +778,11 @@ def _conv1d_pallas_bwd(spec, res, gout):
     else:
         wblk = bw.wblk or spec.wblk
         bw_nblk = _legal_nblk(bw.nblk, N)
-        bw_alg = bw.alg or "tap_loop"
         bw_pipe = _k.canon_pipe(bw.pipe)
+        bw_alg = bw.alg or pick_alg(
+            "bwd_weight", N=N, C=C, K=K, S=S, dilation=d, Q=Q,
+            dtypes=(x.dtype, du.dtype), wblk=wblk, nblk=bw_nblk,
+            pipe=bw_pipe)
         Qp = _round_up(Q, wblk)
         xp = (jnp.pad(x, ((0, 0), (0, 0), (0, Qp + span - W)))
               if Qp + span > W else x)
@@ -815,11 +875,12 @@ def conv1d(
 
     ``alg`` pins the dense contraction formulation (``tap_loop`` /
     ``tap_packed``, DESIGN.md §12) and ``nblk`` the batch fold of the
-    forward kernel; both default to the tuner's choice under
-    backend='auto' and to the historical kernel otherwise.  ``pipe`` pins
-    the forward's software-pipeline depth (DESIGN.md §15): 0/1 the
-    synchronous kernel, >= 2 the double-buffered async-copy variant —
-    numerically identical, tuner-selected under backend='auto'.
+    forward kernel; under backend='auto' both default to the tuner's
+    choice.  An ``alg`` that neither pins is read from each pass's shape
+    (``pick_alg``: skinny passes pack their taps), and ``nblk`` is 1.
+    ``pipe`` pins the forward's software-pipeline depth (DESIGN.md §15):
+    0/1 the synchronous kernel, >= 2 the double-buffered async-copy
+    variant — numerically identical, tuner-selected under backend='auto'.
 
     backend='auto' asks the tuning subsystem (``repro.tune``) to pick the
     backend and tile sizes **per pass**: the forward's, plus each backward
@@ -908,12 +969,19 @@ def conv1d(
     elif backend == "pallas":
         wblk = wblk or pick_wblk(Q, S, dilation)
         interpret = _interpret_default() if interpret is None else interpret
+        nblk = _legal_nblk(nblk, N)
+        pipe = _k.canon_pipe(pipe)
+        alg = alg or pick_alg(
+            "fwd", N=N, C=C, K=K, S=S, dilation=dilation, Q=Q,
+            dtypes=(x.dtype, w.dtype, _dtype_name(residual), out_dtype),
+            wblk=wblk, kblk=kblk, nblk=nblk, pipe=pipe,
+            epilogue=_ep.signature(bias is not None, activation,
+                                   residual is not None))
         spec = _FusedSpec(dilation, wblk, kblk, interpret, activation,
                           _dtype_name(bias), _dtype_name(residual),
                           jnp.dtype(out_dtype).name if out_dtype else None,
-                          bwd_data_cfg, bwd_weight_cfg,
-                          alg or "tap_loop", _legal_nblk(nblk, x.shape[0]),
-                          grad_reduce_axes, _k.canon_pipe(pipe),
+                          bwd_data_cfg, bwd_weight_cfg, alg, nblk,
+                          grad_reduce_axes, pipe,
                           int(grad_reduce_chunks or 1)
                           if grad_reduce_axes else 1,
                           model_axes=model_reduce_axes,

@@ -6,7 +6,8 @@ once and compiled ahead of time.  The compiled HLO's metadata must name
 the layer of every conv kernel op, pad and slice, forward and backward,
 and the optimizer's ops must carry ``step.optimizer``: the device trace
 reads these names.  The trace must count one kernel build per Pallas
-call, across fewer distinct kernel signatures.
+call, across fewer distinct kernel signatures, and count the builds that
+took the tap-packed formulation.
 """
 from __future__ import annotations
 
@@ -120,3 +121,22 @@ def test_build_counters_count_every_pallas_call(step):
     assert counts["kernels.build"] == sum(calls.values())
     assert 0 < counts["kernels.build_distinct"] < counts["kernels.build"]
     assert counts["kernels.build_s"] > 0
+    # every pass of this skinny net packs its taps, but the two heads'
+    # weight gradients (K=1 streamed rows against C=15 packed columns)
+    assert counts["kernels.build_packed"] == counts["kernels.build"] - 2
+
+
+def test_fat_channel_conv_builds_no_packed_kernel():
+    """C=K=128 fills the MXU tile already: all three passes keep the
+    tap loop, and the packed-build counter stays at 0."""
+    from repro.kernels import ops
+
+    x = jnp.ones((1, 128, 256))
+    w = jnp.ones((3, 128, 128))
+    obs.reset_counters()
+    jax.jit(jax.grad(lambda x, w: ops.conv1d(
+        x, w, dilation=2, backend="pallas").sum(), argnums=(0, 1))).trace(x, w)
+    counts = obs.counters()
+    obs.reset_counters()
+    assert counts["kernels.build"] == 3
+    assert counts.get("kernels.build_packed", 0) == 0

@@ -7,6 +7,9 @@ Covers the second dense-kernel formulation end to end:
     AND jax.grad — including non-divisible widths and nblk > 1;
   * spy test: ``backend='auto'`` dispatches exactly the alg/nblk the
     cache records, per pass;
+  * shape rule: an untuned pass packs when its packed GEMM dimension is
+    under one MXU tile and the packed operand fits VMEM, and a pinned or
+    tuned alg wins over it;
   * candidate space: alg/nblk axes with per-pass legality + VMEM
     accounting (packed operand charged), constraint keys (``|alg:`` /
     ``|nblk:``) round-tripping while legacy entries stay readable;
@@ -263,6 +266,94 @@ def test_nblk_not_dividing_batch_sanitizes_to_one(tmp_cache, monkeypatch):
     assert y.shape == (3, 8, 128)
     assert fwd_calls[0]["nblk"] == 1
     assert fwd_calls[0]["alg"] == "tap_packed"
+
+
+# ---------------------------------------------------------------------------
+# Shape rule: the untuned dense pass picks its formulation from its GEMM
+# ---------------------------------------------------------------------------
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+# (C, K, S, d, dtypes as _kernel_pass sees them: x then the weight)
+ATAC = dict(S=51, d=8)
+RULE_CASES = [
+    # AtacWorks body: fp32 C=K=15, bf16 weights on fp32 activations C=K=16
+    ("body-fp32", dict(C=15, K=15, **ATAC, dtypes=(F32, F32)),
+     dict(fwd="tap_packed", bwd_data="tap_packed", bwd_weight="tap_packed")),
+    ("body-bf16", dict(C=16, K=16, **ATAC, dtypes=(F32, BF16)),
+     dict(fwd="tap_packed", bwd_data="tap_packed", bwd_weight="tap_packed")),
+    ("stem", dict(C=1, K=15, **ATAC, dtypes=(F32, F32)),
+     dict(fwd="tap_packed", bwd_data="tap_packed", bwd_weight="tap_packed")),
+    # heads: bwd-weight streams K=1 (padded to 8) rows against 16 packed
+    # columns a tap, where the tap loop is as fast (PERF.md §6)
+    ("head", dict(C=15, K=1, **ATAC, dtypes=(F32, F32)),
+     dict(fwd="tap_packed", bwd_data="tap_packed", bwd_weight="tap_loop")),
+    # fat channels already fill an MXU tile: nothing to pack
+    ("fat-C128", dict(C=128, K=128, S=5, d=1, dtypes=(F32, F32)),
+     dict(fwd="tap_loop", bwd_data="tap_loop", bwd_weight="tap_loop")),
+    # one tap: the packed GEMM is the tap loop
+    ("S1", dict(C=15, K=15, S=1, d=1, dtypes=(F32, F32)),
+     dict(fwd="tap_loop", bwd_data="tap_loop", bwd_weight="tap_loop")),
+    # 64 taps of 64 channels: the packed operand alone is over budget
+    ("vmem", dict(C=64, K=64, S=64, d=1, dtypes=(F32, F32)),
+     dict(fwd="tap_loop", bwd_data="tap_loop", bwd_weight="tap_loop")),
+]
+
+
+@pytest.mark.parametrize("pass_", tune.PASSES)
+@pytest.mark.parametrize("case,shape,want", RULE_CASES,
+                         ids=[c[0] for c in RULE_CASES])
+def test_untuned_pass_picks_alg_from_shape(case, shape, want, pass_):
+    alg = ops.pick_alg(pass_, N=64, C=shape["C"], K=shape["K"], S=shape["S"],
+                       dilation=shape["d"], Q=60_000, dtypes=shape["dtypes"],
+                       wblk=512)
+    assert alg == want[pass_]
+    if case == "vmem":   # the loop is kept for the budget, not the width
+        prob = tune.ConvProblem(N=64, C=64, K=64, S=64, dilation=1,
+                                Q=60_000, dtype="float32", pass_=pass_)
+        assert prob.contraction < cost.MXU_DIM
+        assert space.vmem_footprint_bytes(
+            prob, 512, None, "tap_packed") > space.VMEM_BUDGET_BYTES
+
+
+def _pass_algs(monkeypatch, **conv_kw):
+    """(fwd, bwd_data, bwd_weight) formulations jax.grad of one conv
+    dispatches to the kernels."""
+    fwd_calls = _spy(monkeypatch, "conv1d_fwd")
+    bwdw_calls = _spy(monkeypatch, "conv1d_bwd_weight")
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((2, 8, 256)).astype(np.float32))
+    w = jnp.asarray(0.1 * rng.standard_normal((3, 16, 8)).astype(np.float32))
+    jax.grad(lambda x, w: ops.conv1d(x, w, dilation=2, padding="SAME",
+                                     **conv_kw).sum(),
+             argnums=(0, 1))(x, w)
+    return fwd_calls[0]["alg"], fwd_calls[1]["alg"], bwdw_calls[0]["alg"]
+
+
+@pytest.mark.parametrize("how,want", [
+    ("untuned", ("tap_packed",) * 3),
+    ("pinned", ("tap_loop",) * 3),
+    ("tuned", ("tap_loop", "tap_packed", "tap_loop")),
+])
+def test_pinned_and_tuned_alg_win_over_shape_rule(tmp_cache, monkeypatch,
+                                                  how, want):
+    """The shape rule fills only what nobody chose: an explicit ``alg``
+    (and per-pass config) and a tuner cache entry win per pass."""
+    if how == "untuned":
+        kw = dict(backend="pallas")
+    elif how == "pinned":
+        cfg = ops.PassConfig("pallas", 128, None, "tap_loop")
+        kw = dict(backend="pallas", alg="tap_loop", bwd_data_cfg=cfg,
+                  bwd_weight_cfg=cfg)
+    else:
+        p = tune.ConvProblem(N=2, C=8, K=16, S=3, dilation=2, Q=256,
+                             dtype="float32", padding="SAME")
+        cache, dk = tune.get_default_cache(), tune.device_kind()
+        for pass_, alg in zip(tune.PASSES, want):
+            cache.put(p.with_pass(pass_).key(dk),
+                      {"backend": "pallas", "wblk": 128, "kblk": None,
+                       "alg": alg, "nblk": 1})
+        kw = dict(backend="auto")
+    assert _pass_algs(monkeypatch, **kw) == want
 
 
 # ---------------------------------------------------------------------------

@@ -76,6 +76,25 @@ def test_dense_conv_grad_compiles_for_v5e(one_chip, alg, pipe, C, dtype):
     assert _pallas_kernels(compiled) >= 3
 
 
+@pytest.mark.parametrize("C,dtype", [(15, jnp.float32), (16, jnp.bfloat16)],
+                         ids=["fp32-C15", "bf16-C16"])
+def test_untuned_dense_conv_grad_compiles_for_v5e(one_chip, C, dtype):
+    """The path the training step takes: no alg and no pass configs, so
+    each pass picks its formulation from its shape; at the AtacWorks body
+    shape all three pick tap_packed.  The bf16 model's activations stay
+    float32, as in training."""
+    from repro import obs
+
+    obs.reset_counters()
+    compiled = _compile_grad(ops.conv1d, one_chip, C=C, dtype=dtype,
+                             x_dtype=jnp.float32, w_shape=(S, C, C),
+                             bias=(C,))
+    counts = obs.counters()
+    obs.reset_counters()
+    assert counts["kernels.build"] == counts["kernels.build_packed"] == 3
+    assert _pallas_kernels(compiled) >= 3
+
+
 @pytest.mark.parametrize("C,dtype,pipe", [(15, jnp.float32, 0),
                                           (16, jnp.bfloat16, 2)],
                          ids=["fp32-C15-pipe0", "bf16-C16-pipe2"])
