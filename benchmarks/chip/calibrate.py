@@ -3,7 +3,8 @@
     python3 -m benchmarks.chip.calibrate --workload train.atacworks.b64 \
         --seeds 11,12,...  --control-seeds 11,12,13 --out readings.json
 
-In one process, so the program compiles once:
+In one process, so the program compiles once, the driver of the cell's
+traffic kind (``drivers/<kind>.py``, its ``calibrate``) reads:
 
 * the program's readings on every seed in ``--seeds``: the timed path
   compared with the plain reference, as a run compares them (the lower
@@ -11,14 +12,11 @@ In one process, so the program compiles once:
 * on each seed in ``--control-seeds``: the control, which is the
   reference computed one precision step below the configuration's dtype
   and put in the program's place, and each fault the cell can have,
-  planted in the reference (training: half of the batch left out; on a
-  bf16 cell also the gradient exchange of four data shards left out) or
-  in the program (serving: ring buffers left unchanged, an answer
-  altered).  A train step that returns its state unchanged reads
-  update_gap = 1 by construction and needs no run.
+  planted in the reference or in the program.
 
 The upper reading of a number is the least that the control and the
-faults give.  ``--seconds`` is the serving window per seed.
+faults give.  ``--seconds`` is the window per seed of a driver that needs
+one (serving).
 """
 from __future__ import annotations
 
@@ -27,63 +25,32 @@ import json
 import sys
 import time
 
-from benchmarks.chip.lib import device, faults, judge, spec as specmod
+from benchmarks.chip.lib import device, spec as specmod
 from benchmarks.chip import run as runmod
 
 
-def _train(spec, devices, seeds, control_seeds, log):
-    from benchmarks.chip.drivers.train import TrainCell
+def readings(spec: dict, devices, seeds, control_seeds, seconds: float):
+    """The cell's driver's readings, as rows, with each number's lower
+    reading (the largest over the program's rows) and upper reading (the
+    least over the control's and the faults' rows)."""
+    rows = []
 
-    cell = TrainCell(spec, devices)
-    low = cell.ref.LOWER[cell.config["dtype"]]
-    for seed in seeds:
-        t = time.perf_counter()
-        state, pool, params0 = cell.start(seed)
-        state, prog = cell.first_steps(state, pool)
-        del state
-        ref = cell.reference_readings(params0, pool)
-        log("program", seed, judge.train_numbers(prog, ref, params0), t)
-        if seed not in control_seeds:
-            continue
-        variants = {"control": dict(low=low),
-                    "half_batch": dict(fault="half_batch")}
-        if cell.config["dtype"] == "bfloat16":
-            variants["no_exchange"] = dict(fault="no_exchange", dp=4)
-        for name, kw in variants.items():
-            t = time.perf_counter()
-            other = cell.reference_readings(params0, pool, **kw)
-            log(name, seed, judge.train_numbers(other, ref, params0), t)
+    def log(kind, seed, numbers, t0, **extra):
+        rows.append({"kind": kind, "seed": seed, "numbers": numbers,
+                     "seconds": time.perf_counter() - t0, **extra})
+        print(json.dumps(rows[-1]), file=sys.stderr, flush=True)
 
-
-def _stream(spec, devices, seeds, control_seeds, seconds, log):
-    from benchmarks.chip.drivers.stream import StreamCell
-
-    low = specmod.reference(spec["config"]).LOWER[spec["config"]["dtype"]]
-
-    def session(seed):
-        cell = StreamCell(spec, devices, seed)
-        cell.warm_up()
-        w = cell.window(seconds)
-        return cell, cell.check_sample(w["finished"])
-
-    for seed in seeds:
-        t = time.perf_counter()
-        cell, sample = session(seed)
-        ref = cell.reference_outputs(sample)
-        log("program", seed, judge.stream_numbers(
-            [r.result() for r in sample], ref), t, streams=len(sample))
-        if seed not in control_seeds:
-            continue
-        t = time.perf_counter()
-        log("control", seed, judge.stream_numbers(
-            cell.reference_outputs(sample, low=low), ref), t)
-        for name, plant in faults.STREAM.items():
-            t = time.perf_counter()
-            with plant():
-                bad, bad_sample = session(seed)
-            log(name, seed, judge.stream_numbers(
-                [r.result() for r in bad_sample],
-                bad.reference_outputs(bad_sample)), t)
+    specmod.driver(spec["traffic"]["kind"]).calibrate(
+        spec, devices, seeds, control_seeds, seconds, log)
+    lower = {}
+    upper = {}
+    for r in rows:
+        for k, v in r["numbers"].items():
+            if r["kind"] == "program":
+                lower[k] = max(lower.get(k, 0.0), v)
+            else:
+                upper[k] = min(upper.get(k, float("inf")), v)
+    return rows, lower, upper
 
 
 def main(argv=None) -> int:
@@ -100,25 +67,8 @@ def main(argv=None) -> int:
     runmod._use_compile_cache()
     seeds = [int(s) for s in args.seeds.split(",")]
     control = {int(s) for s in args.control_seeds.split(",") if s}
-    rows = []
-
-    def log(kind, seed, numbers, t0, **extra):
-        rows.append({"kind": kind, "seed": seed, "numbers": numbers,
-                     "seconds": time.perf_counter() - t0, **extra})
-        print(json.dumps(rows[-1]), file=sys.stderr, flush=True)
-
-    if spec["traffic"]["kind"] == "train":
-        _train(spec, devices, seeds, control, log)
-    else:
-        _stream(spec, devices, seeds, control, args.seconds, log)
-    lower = {}
-    upper = {}
-    for r in rows:
-        for k, v in r["numbers"].items():
-            if r["kind"] == "program":
-                lower[k] = max(lower.get(k, 0.0), v)
-            else:
-                upper[k] = min(upper.get(k, float("inf")), v)
+    rows, lower, upper = readings(spec, devices, seeds, control,
+                                  args.seconds)
     summary = {"workload": args.workload, "device": device.describe(devices),
                "lower": lower, "upper": upper, "rows": rows}
     with open(args.out, "w") as f:

@@ -22,7 +22,7 @@ import time
 import jax
 import numpy as np
 
-from benchmarks.chip.lib import judge, traffic as gen
+from benchmarks.chip.lib import faults, judge, traffic as gen
 from benchmarks.chip.lib import spec as specmod
 from benchmarks.chip.drivers.train import program_config
 
@@ -182,3 +182,49 @@ def run(spec: dict, devices, *, seed: int, seconds: float, trace_dir,
                  "streams_finished": len(w["finished"]),
                  "streams_checked": len(sample)},
     }
+
+
+def calibrate(spec: dict, devices, seeds, control_seeds, seconds, log):
+    """Readings for the limits (``benchmarks.chip.calibrate``): on every
+    seed a window of ``seconds`` and the sample it finished against the
+    reference; on each control seed the control (the reference one
+    precision step below the stated dtype) on the same sample, and each
+    fault of ``lib.faults.STREAM`` planted in the program, in a session of
+    its own."""
+    low = specmod.reference(spec["config"]).LOWER[spec["config"]["dtype"]]
+
+    def session(seed):
+        cell = StreamCell(spec, devices, seed)
+        cell.warm_up()
+        w = cell.window(seconds)
+        return cell, cell.check_sample(w["finished"])
+
+    for seed in seeds:
+        t = time.perf_counter()
+        cell, sample = session(seed)
+        ref = cell.reference_outputs(sample)
+        log("program", seed, judge.stream_numbers(
+            [r.result() for r in sample], ref), t, streams=len(sample))
+        if seed not in control_seeds:
+            continue
+        t = time.perf_counter()
+        log("control", seed, judge.stream_numbers(
+            cell.reference_outputs(sample, low=low), ref), t)
+        for name, plant in faults.STREAM.items():
+            t = time.perf_counter()
+            with plant():
+                bad, bad_sample = session(seed)
+            log(name, seed, judge.stream_numbers(
+                [r.result() for r in bad_sample],
+                bad.reference_outputs(bad_sample)), t)
+
+
+def tiny(spec: dict) -> dict:
+    """``spec`` cut down in place for a run on the CPU: a 5-tap filter at
+    dilation 2, two slots of 16-column chunks, short streams."""
+    spec["config"].update(conv_filter=5, conv_dilation=2)
+    spec["traffic"].update(
+        slots=2, chunk=16, history=16, warmup_steps=8, check_streams=4,
+        lengths={"median": 64, "sigma": 1.0, "min": 32, "max": 256,
+                 "n": 64, "strata": 8})
+    return spec

@@ -191,3 +191,41 @@ def run(spec: dict, devices, *, seed: int, seconds: float, trace_dir,
                  "step_s_median": float(np.median(step_s)),
                  "step_s_max": float(step_s.max())},
     }
+
+
+def calibrate(spec: dict, devices, seeds, control_seeds, seconds, log):
+    """Readings for the limits (``benchmarks.chip.calibrate``): on every
+    seed the program's first steps against the reference; on each control
+    seed the control (the reference one precision step below the stated
+    dtype) and the faults, planted in the reference: half of the batch
+    left out and, on a bf16 configuration, the gradient exchange of four
+    data shards left out.  A step that returns its state unchanged reads
+    update_gap = 1 by construction and needs no run.  ``seconds`` is not
+    used: training needs no window."""
+    cell = TrainCell(spec, devices)
+    low = cell.ref.LOWER[cell.config["dtype"]]
+    for seed in seeds:
+        t = time.perf_counter()
+        state, pool, params0 = cell.start(seed)
+        state, prog = cell.first_steps(state, pool)
+        del state
+        ref = cell.reference_readings(params0, pool)
+        log("program", seed, judge.train_numbers(prog, ref, params0), t)
+        if seed not in control_seeds:
+            continue
+        variants = {"control": dict(low=low),
+                    "half_batch": dict(fault="half_batch")}
+        if cell.config["dtype"] == "bfloat16":
+            variants["no_exchange"] = dict(fault="no_exchange", dp=4)
+        for name, kw in variants.items():
+            t = time.perf_counter()
+            other = cell.reference_readings(params0, pool, **kw)
+            log(name, seed, judge.train_numbers(other, ref, params0), t)
+
+
+def tiny(spec: dict) -> dict:
+    """``spec`` cut down in place for a run on the CPU: a 5-tap filter at
+    dilation 2, batch 8 of 512-column segments."""
+    spec["config"].update(conv_filter=5, conv_dilation=2)
+    spec["traffic"].update(batch=8, width=512, pad=40)
+    return spec

@@ -1,13 +1,24 @@
-"""Find a cell's files by the names ``BENCHMARK.json`` gives them."""
+"""Find a cell's files by the names ``BENCHMARK.json`` gives them.
+
+Every function takes the checkout's ``root``, so a test can load a copy of
+the benchmark with files added to it.  A traffic mix's ``kind`` names its
+driver, the module ``benchmarks.chip.drivers.<kind>``."""
 from __future__ import annotations
 
 import functools
+import importlib
 import importlib.util
 import json
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
+DRIVERS = "benchmarks.chip.drivers"
+
+
+def bench_dir(root: Path = ROOT) -> Path:
+    """This directory's place in the checkout at ``root``."""
+    return Path(root) / BENCH_DIR.relative_to(ROOT)
 
 
 class SpecError(ValueError):
@@ -22,7 +33,7 @@ def _read_json(path: Path) -> dict:
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
-    return _read_json(root / "BENCHMARK.json")
+    return _read_json(Path(root) / "BENCHMARK.json")
 
 
 def _by_name(entries: list[dict], name: str, what: str) -> dict:
@@ -45,11 +56,12 @@ def load_cell(workload: str, root: Path = ROOT) -> dict:
     layer = [m for m in bench["per_layer"]
              if workload in m.get("workloads", [])
              or ("workloads" not in m and m["moves"] in moved)]
+    here = bench_dir(root)
     return {
         "cell": cell,
-        "config": _read_json(root / cfg_entry["file"]),
-        "traffic": _read_json(BENCH_DIR / "traffic" / f"{cell['traffic']}.json"),
-        "limits": _read_json(BENCH_DIR / "limits" / f"{workload}.json"),
+        "config": _read_json(Path(root) / cfg_entry["file"]),
+        "traffic": _read_json(here / "traffic" / f"{cell['traffic']}.json"),
+        "limits": _read_json(here / "limits" / f"{workload}.json"),
         "end_to_end": e2e,
         "per_layer": layer,
     }
@@ -65,15 +77,27 @@ def _load_module(path: Path, name: str):
     return mod
 
 
-def layer_reader(metric: str):
+def layer_reader(metric: str, root: Path = ROOT):
     """The ``read(record)`` function of ``layer_metrics/<metric>.py``."""
-    mod = _load_module(BENCH_DIR / "layer_metrics" / f"{metric}.py",
+    mod = _load_module(bench_dir(root) / "layer_metrics" / f"{metric}.py",
                        f"_chipbench_metric_{metric.replace('.', '_')}")
     return mod.read
 
 
-def reference(config: dict):
+def reference(config: dict, root: Path = ROOT):
     """The plain reference module the configuration names."""
     name = config["reference"]
-    return _load_module(BENCH_DIR / "references" / f"{name}.py",
+    return _load_module(bench_dir(root) / "references" / f"{name}.py",
                         f"_chipbench_reference_{name}")
+
+
+def driver(kind: str):
+    """The driver module of a traffic ``kind``: it builds, warms up and
+    measures a cell (``run``), reads a cell's calibration (``calibrate``)
+    and cuts a cell down for the CPU tests (``tiny``)."""
+    try:
+        return importlib.import_module(f"{DRIVERS}.{kind}")
+    except ModuleNotFoundError as e:
+        if e.name != f"{DRIVERS}.{kind}":
+            raise
+        raise SpecError(f"no driver for traffic kind {kind!r}") from None

@@ -162,9 +162,10 @@ class _Innermost:
 def summarize(events: list[Event]) -> dict:
     """Reduce a trace to the numbers the per-layer readers take.
 
-    Times are seconds.  ``busy_s`` and ``collective_exposed_s`` are means
-    over the chips in the trace; ``kernel_s`` sums every chip's events per
-    conv pass; ``device_ops`` and ``idle_gaps`` are the ten largest totals
+    Times are seconds.  ``busy_s``, ``collective_s`` (the union of a
+    chip's collective ops) and ``collective_exposed_s`` are means over the
+    chips in the trace; ``kernel_s`` sums every chip's events per conv
+    pass; ``device_ops`` and ``idle_gaps`` are the ten largest totals
     by op stem and by what the host was doing."""
     w0, w1 = _window(events)
     per_dev: dict[str, list[tuple[float, float, str]]] = {}
@@ -175,7 +176,7 @@ def summarize(events: list[Event]) -> dict:
             (max(e.start_ns, w0), min(e.end_ns, w1), e.name))
     if not per_dev:
         raise ValueError("no device op ran inside the traced window")
-    busy, exposed = [], []
+    busy, collective, exposed = [], [], []
     kernel = {s: 0.0 for s in CONV_STEMS}
     ops: dict[str, float] = {}
     for ivs in per_dev.values():
@@ -183,6 +184,7 @@ def summarize(events: list[Event]) -> dict:
         coll = union((s, e) for s, e, n in ivs if is_collective(n))
         other = union((s, e) for s, e, n in ivs if not is_collective(n))
         busy.append(length(all_u))
+        collective.append(length(coll))
         exposed.append(length(coll) - overlap(coll, other))
         for s, e, n in ivs:
             k = stem(n)
@@ -218,6 +220,7 @@ def summarize(events: list[Event]) -> dict:
         "window_s": (w1 - w0) / 1e9,
         "busy_s": sum(busy) / n_dev / 1e9,
         "kernel_s": {k: v / 1e9 for k, v in kernel.items()},
+        "collective_s": sum(collective) / n_dev / 1e9,
         "collective_exposed_s": sum(exposed) / n_dev / 1e9,
         "device_ops": top(ops),
         "idle_gaps": top(named),

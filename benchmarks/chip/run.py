@@ -29,7 +29,6 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import sys  # noqa: E402
@@ -118,8 +117,7 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
         _use_compile_cache()
     dev = device.describe(devices)
     peaks = device.PEAKS.get(dev["kind"])
-    driver = importlib.import_module(
-        f"benchmarks.chip.drivers.{spec['traffic']['kind']}")
+    driver = specmod.driver(spec["traffic"]["kind"])
     compiles = _CompileClock()
     marks.append(("harness", time.perf_counter()))
     try:

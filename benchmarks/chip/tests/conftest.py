@@ -1,5 +1,5 @@
-"""Cut-down cells for the CPU: the real cell's files, with the filter,
-dilation, widths and counts made small enough for a test run."""
+"""Cut-down cells for the CPU: the real cell's files, cut by its traffic
+kind's driver (``tiny``) small enough for a test run."""
 from __future__ import annotations
 
 import json
@@ -30,18 +30,12 @@ def stream_spec() -> dict:
             "per_layer": data["per_layer"]}
 
 
-def tiny_spec(workload: str) -> dict:
-    spec = stream_spec() if workload == STREAM else specmod.load_cell(workload)
-    spec["config"].update(conv_filter=5, conv_dilation=2)
-    t = spec["traffic"]
-    if t["kind"] == "train":
-        t.update(batch=8, width=512, pad=40)
-    else:
-        t.update(slots=2, chunk=16, history=16, warmup_steps=8,
-                 check_streams=4,
-                 lengths={"median": 64, "sigma": 1.0, "min": 32, "max": 256,
-                          "n": 64, "strata": 8})
-    return spec
+def tiny_spec(workload: str, root: Path = specmod.ROOT) -> dict:
+    """The cell of ``BENCHMARK.json`` at ``root`` (or the tests' stream
+    cell), cut down by its driver."""
+    spec = (stream_spec() if workload == STREAM
+            else specmod.load_cell(workload, root))
+    return specmod.driver(spec["traffic"]["kind"]).tiny(spec)
 
 
 @pytest.fixture
@@ -55,10 +49,11 @@ def run_tiny():
     returns its result line and the window's diagnostics."""
     from benchmarks.chip import run
 
-    def go(workload: str, *, seed: int = 2**31 + 17, spec=None, devices=1):
+    def go(workload: str, *, seed: int = 2**31 + 17, spec=None, devices=1,
+           seconds: float = SECONDS):
         spec = spec or tiny_spec(workload)
         spec["cell"] = dict(spec["cell"], chips=devices)
-        return run.run_cell(workload, seed=seed, seconds=SECONDS,
+        return run.run_cell(workload, seed=seed, seconds=seconds,
                             trace=False, require_tpu=False, spec=spec,
                             t0=time.perf_counter())
 

@@ -1,7 +1,14 @@
-"""The bf16 training cell on four chips, as the launcher's data-parallel
-step runs it, on four virtual CPU devices in a child process (the device
-count is fixed when JAX starts): a sound run agrees with the reference,
-and one whose gradient exchange is left out does not."""
+"""The four-chip cell ``train.atacworks-bf16.dp4``, cut down, as the
+launcher's data-parallel step runs it, on four virtual CPU devices in a
+child process (the device count is fixed when JAX starts): a sound run
+agrees with the reference and, traced, reports the exposed collective
+time; a run with each fault the cell can have planted in the program (the
+gradient exchange left out, the state returned unchanged, half of each
+chip's batch left out) does not agree.
+
+A CPU profile holds no device ops, so the traced run reads a hand-made
+four-chip trace in place of its own: on each chip an all-reduce
+[450, 600] ns under a fusion [0, 500] ns, so 100 ns of it exposed."""
 from __future__ import annotations
 
 import json
@@ -10,26 +17,29 @@ import subprocess
 import sys
 from pathlib import Path
 
+CELL = "train.atacworks-bf16.dp4"
 CHILD = """
 import json, sys
 sys.path.insert(0, {tests!r})
 from conftest import tiny_spec
 from benchmarks.chip import run
-from benchmarks.chip.lib import faults
+from benchmarks.chip.lib import faults, trace as tr
+
+tr.load = lambda log_dir: [tr.Event("host", "python", tr.WINDOW_SPAN, 0., 1e3)] + [
+    tr.Event("device", str(c), name, s, e) for c in range(4)
+    for name, s, e in (("fusion.1", 0., 500.), ("all-reduce.7", 450., 600.))]
+plants = dict(faults.TRAIN, no_exchange=faults.train_no_exchange)
 out = {{}}
-for name in ("sound", "no_exchange"):
-    spec = tiny_spec("train.atacworks-bf16.b64")
-    spec["cell"]["chips"] = 4
-    if name == "sound":
-        r, _ = run.run_cell(spec["cell"]["name"], seed=2**33 + 5,
-                            seconds=0.5, trace=False, require_tpu=False,
-                            spec=spec)
+for name in ("sound", "traced", *plants):
+    spec = tiny_spec({cell!r})
+    kw = dict(seed=2**33 + 5, seconds=0.5, trace=name == "traced",
+              require_tpu=False, spec=spec)
+    if name in plants:
+        with plants[name]():
+            r, _ = run.run_cell({cell!r}, **kw)
     else:
-        with faults.train_no_exchange():
-            r, _ = run.run_cell(spec["cell"]["name"], seed=2**33 + 5,
-                                seconds=0.5, trace=False, require_tpu=False,
-                                spec=spec)
-    out[name] = [r["correct"], r["device"]["count"], r["checks"]]
+        r, _ = run.run_cell({cell!r}, **kw)
+    out[name] = r
 print(json.dumps(out))
 """
 
@@ -42,9 +52,15 @@ def test_dp4_exchange_is_checked():
         [str(root), str(root / "src"), env.get("PYTHONPATH", "")])
     proc = subprocess.run(
         [sys.executable, "-c",
-         CHILD.format(tests=str(Path(__file__).parent))],
+         CHILD.format(tests=str(Path(__file__).parent), cell=CELL)],
         cwd=root, env=env, capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0, proc.stderr[-4000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["sound"][0] and out["sound"][1] == 4, out["sound"]
-    assert not out["no_exchange"][0], out["no_exchange"]
+    sound, traced = out["sound"], out["traced"]
+    for r in (sound, traced):
+        assert r["correct"] and r["device"]["count"] == 4, r
+    assert set(sound["metrics"]) == {"train_segments_per_s", "setup_s"}
+    assert traced["metrics"]["dp_collective_exposed_ms"]["value"] == \
+        1e3 * 100e-9 / traced["attempted"]
+    for fault in ("no_exchange", "state_unchanged", "half_batch"):
+        assert not out[fault]["correct"], (fault, out[fault]["checks"])

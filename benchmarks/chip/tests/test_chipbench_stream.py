@@ -11,10 +11,13 @@ import pytest
 from benchmarks.chip.lib import faults, judge
 
 CELL = "stream.atacworks.c128"  # tests/data/stream_cell.json
+# long enough that a CPU shared with other test workers still finishes the
+# four streams the check compares (about 15 finish in 0.5 s on an idle one)
+SOUND_SECONDS = 2.0
 
 
 def test_sound_run_is_correct(run_tiny):
-    r, diag = run_tiny(CELL)
+    r, diag = run_tiny(CELL, seconds=SOUND_SECONDS)
     assert r["correct"], r["checks"]
     assert diag["compiles_in_window"] == 0
     assert r["attempted"] >= 4 and r["failed"] == 0

@@ -49,6 +49,7 @@ def test_hand_trace():
         "conv1d_bwd_weight": 35 * NS})
     # chip 0: all-reduce [38,44] under fusion.12 until 40 -> 4 exposed;
     # chip 1: [25,35] alone -> 10
+    assert s["collective_s"] == pytest.approx(8 * NS)
     assert s["collective_exposed_s"] == pytest.approx(7 * NS)
     ops = dict(s["device_ops"])
     assert ops["all-reduce"] == pytest.approx(16 * NS)
@@ -60,6 +61,18 @@ def test_hand_trace():
         "bench.train.step / PjitFunction(step)": 3 * NS,
         "bench.train.step / TransferToHost": 11 * NS,
         "bench.train.step / -": 10 * NS})
+
+
+def test_dp_collective_exposed_ms_is_a_chip_mean_per_step():
+    """Of the hand trace's two steps, 7 ns a chip exposed on average."""
+    from benchmarks.chip.lib import spec as specmod
+
+    read = specmod.layer_reader("dp_collective_exposed_ms")
+    assert read({"trace": tr.summarize(HAND), "steps": 2}) == \
+        pytest.approx(1e3 * 3.5 * NS)
+    no_collective = [e for e in HAND if not tr.is_collective(e.name)]
+    assert read({"trace": tr.summarize(no_collective), "steps": 2}) is None
+    assert read({"trace": None, "steps": 2}) is None
 
 
 def test_no_window_or_no_device_is_an_error():
@@ -92,6 +105,6 @@ def test_recorded_train_excerpt():
     assert kernel <= s["busy_s"] * s["devices"]
     assert sum(v for _, v in s["idle_gaps"]) == pytest.approx(
         s["window_s"] - s["busy_s"])
-    assert s["collective_exposed_s"] == 0.0
+    assert s["collective_exposed_s"] == s["collective_s"] == 0.0
     # HLO instructions are named by the text before " = "
     assert "pad" in dict(s["device_ops"])

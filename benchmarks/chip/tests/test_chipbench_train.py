@@ -29,10 +29,13 @@ def test_planted_fault_is_caught(run_tiny, fault):
 
 
 @pytest.mark.parametrize("workload", ["train.atacworks.b64",
-                                      "train.atacworks-bf16.b64"])
+                                      "train.atacworks-bf16.b64",
+                                      "train.atacworks-bf16.dp4"])
 def test_control_is_not_correct(tiny, workload):
     """The reference one precision step below the configuration's dtype,
-    put in the program's place, fails the cell's limits."""
+    put in the program's place, fails the cell's limits (the four-chip
+    cell's too: the reference's numbers do not depend on the chips it
+    runs on)."""
     from benchmarks.chip.drivers.train import TrainCell
 
     spec = tiny(workload)
